@@ -1,51 +1,10 @@
-"""Pod-scale INA: per-link traffic + measured wall time of the psum modes
-on 8 host devices (subprocess; the beyond-paper datacenter experiment),
-plus the mesh-collective sweep over the NoC collective subsystem
-(mesh size x collective x algorithm x router semantics x E PEs/router).
+"""Mesh-collective sweep over the NoC collective subsystem (simulated:
+mesh size x collective x algorithm x router semantics x E PEs/router).
 
-Run:  PYTHONPATH=src python benchmarks/bench_collectives.py [--mesh-only]
+The psum modes on real chips are compared by ``chip_smoke.py --chips 4``.
+
+Run:  PYTHONPATH=src python benchmarks/bench_collectives.py
 """
-import os
-import subprocess
-import sys
-
-SCRIPT = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import time
-import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
-from repro.core.collectives import per_link_bytes, psum_with_mode
-
-mesh = Mesh(np.array(jax.devices()), ("model",))
-x = jax.random.normal(jax.random.PRNGKey(0), (8, 256, 1024), jnp.float32)
-
-for mode in ("eject_inject", "ina_ring", "ina"):
-    f = jax.jit(shard_map(
-        lambda xs, m=mode: psum_with_mode(xs[0], "model", m)[None],
-        mesh=mesh, in_specs=P("model"), out_specs=P("model"),
-        check_vma=False))
-    f(x).block_until_ready()
-    t0 = time.time()
-    for _ in range(20):
-        out = f(x)
-    out.block_until_ready()
-    us = (time.time() - t0) / 20 * 1e6
-    bpl = per_link_bytes(mode, 8, x[0].nbytes)
-    print(f"collective_{mode},{us:.0f},per_link_bytes={bpl:.0f}")
-"""
-
-
-def run() -> list[str]:
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    proc = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
-                          capture_output=True, text=True, timeout=900)
-    if proc.returncode != 0:
-        return [f"collective_error,0,{proc.stderr[-200:]!r}"]
-    return [l for l in proc.stdout.splitlines() if l.startswith("collective_")]
 
 
 def mesh_sweep(mesh_sizes=(4, 8), e_pes=(1, 4),
@@ -90,5 +49,3 @@ def mesh_sweep(mesh_sizes=(4, 8), e_pes=(1, 4),
 
 if __name__ == "__main__":
     print("\n".join(mesh_sweep()))
-    if "--mesh-only" not in sys.argv:
-        print("\n".join(run()))

@@ -23,9 +23,8 @@ def run() -> list[str]:
     fused = jax.jit(lambda a, b: a @ b)
     eject = jax.jit(lambda a, b: ref.matmul_eject_inject(a, b, bk=512))
 
-    from repro.compat import compiled_cost_analysis
-    cf = compiled_cost_analysis(fused.lower(x, w).compile())
-    ce = compiled_cost_analysis(eject.lower(x, w).compile())
+    cf = fused.lower(x, w).compile().cost_analysis()
+    ce = eject.lower(x, w).compile().cost_analysis()
     extra = ce.get("bytes accessed", 0) - cf.get("bytes accessed", 0)
     model_extra = (k // 512) * m * n * 4 * 2      # write+read per partial
 

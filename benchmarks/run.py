@@ -55,11 +55,6 @@ def _kernels(args):
     return bench_kernels.run()
 
 
-def _collectives(args):
-    from benchmarks import bench_collectives
-    return bench_collectives.run()
-
-
 def _mapper(args):
     from benchmarks import bench_mapper
     return bench_mapper.run(jobs=args.jobs, quick=args.quick)
@@ -119,7 +114,6 @@ SECTIONS = {
     "ws_ina": _ws_ina,
     "ws_vs_os": _ws_vs_os,
     "kernels": _kernels,
-    "collectives": _collectives,
     "mapper": _mapper,
     "mapper_full": _mapper_full,
     "plan": _plan,

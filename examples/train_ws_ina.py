@@ -19,6 +19,7 @@ import jax
 
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.data.pipeline import DataConfig, TokenPipeline
+from repro.launch.mesh import make_host_mesh
 from repro.models.api import get_model
 from repro.optim.adamw import adamw_init
 from repro.parallel.steps import build_train_step
@@ -48,7 +49,7 @@ def main():
         batch, seq = 8, 512
 
     model = get_model(cfg)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_host_mesh(4)            # (data 2, model 4) on 8 devices
     pctx = ParallelCtx(mesh=mesh, psum_mode=args.psum_mode)
     shape = ShapeConfig("train", seq, batch, "train")
     ts = build_train_step(model, mesh, shape, pctx, base_lr=3e-4,

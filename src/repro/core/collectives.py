@@ -36,7 +36,7 @@ from typing import Literal, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
+from jax.lax import axis_size
 
 PsumMode = Literal["ina", "ina_ring", "eject_inject", "xla", "auto"]
 

@@ -13,8 +13,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params
-
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state_ref, *,
             chunk: int):
@@ -30,7 +28,14 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state_ref, *,
     logw = w_ref[0].astype(jnp.float32)       # [C, hd] (negative)
     u = u_ref[0].astype(jnp.float32)          # [1, hd]
 
-    cum = jnp.cumsum(logw, axis=0)            # [C, hd]
+    tpos = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    spos = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # Inclusive prefix sum over the chunk as a lower-triangular matmul:
+    # Mosaic has no cumsum lowering.  HIGHEST keeps the f32 sum exact.
+    tril = (tpos >= spos).astype(jnp.float32)
+    cum = jax.lax.dot_general(tril, logw, (((1,), (0,)), ((), ())),
+                              precision=jax.lax.Precision.HIGHEST,
+                              preferred_element_type=jnp.float32)  # [C, hd]
     cum_prev = cum - logw
     re = r * jnp.exp(cum_prev)
     # Factorized intra-chunk decay: exact while the per-chunk cumulative
@@ -39,8 +44,6 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state_ref, *,
     kf = k * jnp.exp(-jnp.maximum(cum, -80.0))
     scores = jax.lax.dot_general(re, kf, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-    tpos = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    spos = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     scores = jnp.where(tpos > spos, scores, 0.0)      # strictly causal
     diag = jnp.sum(r * u * k, axis=-1, keepdims=True)  # u-bonus (s == t)
 
@@ -51,8 +54,15 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state_ref, *,
                                 preferred_element_type=jnp.float32)
     o_ref[0] = y.astype(o_ref.dtype)
 
-    tail = jnp.exp(cum[-1:] - cum)            # [C, hd]
-    state_ref[...] = state_ref[...] * jnp.exp(cum[-1])[:, None] + \
+    last = jax.lax.slice_in_dim(cum, chunk - 1, chunk, axis=0)   # [1, hd]
+    tail = jnp.exp(last - cum)                # [C, hd]
+    # The chunk's total decay as a column [hd, 1], summed by a transposed
+    # matmul (Mosaic cannot turn the row ``last`` into a column).
+    decay = jax.lax.dot_general(logw, jnp.ones((chunk, 1), jnp.float32),
+                                (((0,), (0,)), ((), ())),
+                                precision=jax.lax.Precision.HIGHEST,
+                                preferred_element_type=jnp.float32)
+    state_ref[...] = state_ref[...] * jnp.exp(decay) + \
         jax.lax.dot_general((k * tail), v, (((0,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
 
@@ -80,7 +90,7 @@ def wkv6(r: jax.Array, k: jax.Array, v: jax.Array, logw: jax.Array,
         out_specs=pl.BlockSpec((1, chunk, hd), lambda b, c: (b, c, 0)),
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((bh, s, hd), r.dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, logw, u3)

@@ -27,7 +27,6 @@ import traceback
 
 import jax
 
-from repro.compat import compiled_cost_analysis
 from repro.configs import ARCHS, SHAPES, shape_applicable
 from repro.launch.mesh import make_production_mesh
 from repro.models.api import get_model
@@ -94,7 +93,7 @@ def _lower_step(cfg, shape, mesh, pctx):
 def _cost_point(cfg, shape, mesh, pctx) -> dict:
     """flops/bytes/collective-bytes of one compiled (per-device) program."""
     compiled = _lower_step(cfg, shape, mesh, pctx).compile()
-    cost = compiled_cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     coll = collective_bytes(compiled.as_text())
     return {"flops": cost.get("flops", 0.0),
             "bytes": cost.get("bytes accessed", 0.0),
@@ -158,7 +157,7 @@ def run_cell(arch: str, shape_name: str, mesh, psum_mode: str = "xla_spmd",
     t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    cost = compiled_cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     coll = collective_bytes(hlo)
 

@@ -1,33 +1,32 @@
-"""Production mesh construction (single-pod 16x16, multi-pod 2x16x16).
+"""Mesh construction (single-pod 16x16, multi-pod 2x16x16, host meshes).
 
-A function, not a module-level constant: importing this module never touches
+Functions, not module-level constants: importing this module never touches
 jax device state (the dry-run sets XLA_FLAGS before first jax init).
 """
 from __future__ import annotations
 
 import jax
-
-try:    # AxisType landed after 0.4; older jax means all-Auto implicitly
-    from jax.sharding import AxisType
-except ImportError:                     # pragma: no cover - jax-dependent
-    AxisType = None
+from jax.sharding import AxisType
 
 
-def _make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(AxisType.Auto,) * len(axes))
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the model code shards through
+    GSPMD propagation and ``with_sharding_constraint``, which Explicit axes
+    (``jax.make_mesh``'s default) reject."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model_parallel: int = 1):
-    """Mesh over whatever devices exist (CPU smoke / small examples)."""
+    """(data, model) mesh over every device this process sees, with
+    ``model_parallel`` devices on the model axis."""
     n = len(jax.devices())
-    mp = max(1, min(model_parallel, n))
-    return _make_mesh((n // mp, mp), ("data", "model"))
+    if model_parallel < 1 or n % model_parallel:
+        raise ValueError(f"model_parallel={model_parallel} does not divide "
+                         f"the {n} available device(s)")
+    return make_mesh((n // model_parallel, model_parallel), ("data", "model"))

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 
 from repro.configs import ARCHS
 from repro.configs.base import ShapeConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.core.collectives import CLI_PSUM_MODES
 from repro.models.api import get_model
@@ -64,13 +65,25 @@ def make_prompts(cfg, batch: int, prompt_len: int):
                               cfg.vocab)
 
 
-def run_engine(args, cfg) -> None:
+def run_engine(args, cfg, requests=None):
+    """Serve ``requests`` (default: ``--batch`` seeded prompts of
+    ``--prompt-len`` tokens, ``--gen`` + 1 new tokens each) through the
+    engine; returns ``(engine, EngineReport)``.  The cache length is the
+    longest request's footprint, rounded up to whole blocks."""
     from repro.serve.batching import Request
     from repro.serve.engine import ServingEngine
 
+    if requests is None:
+        prompts = make_prompts(cfg, args.batch, args.prompt_len)
+        requests = [
+            Request(rid=f"req{i}", prompt_len=args.prompt_len,
+                    max_new=args.gen + 1,
+                    prompt=tuple(int(t) for t in prompts[i]))
+            for i in range(args.batch)]
     mesh = make_host_mesh(args.model_parallel)
-    max_seq = args.prompt_len + args.gen + 1
-    slots = args.slots or args.batch
+    block = args.block_size
+    max_seq = -(-max(r.total_positions for r in requests) // block) * block
+    slots = args.slots or len(requests)
     # one plan per phase: prefill and decode disaggregate
     dshape = ShapeConfig("cli", max_seq, slots, "decode")
     pshape = ShapeConfig("cli", max_seq, slots, "prefill")
@@ -80,39 +93,32 @@ def run_engine(args, cfg) -> None:
     prefill_plan, _ = plan_for_launch(cfg, mesh, pshape, args.psum_mode,
                                       plan_dir=args.plan_dir,
                                       enabled=not args.no_plan)
-    block = args.block_size
-    if max_seq % block:
-        block = 1 << max(0, (max_seq & -max_seq).bit_length() - 1)
-        block = min(block, args.block_size)
-        print(f"[serve] block size {args.block_size} does not divide "
-              f"max_seq {max_seq}; using {block}")
     engine = ServingEngine(
         cfg, slots=slots, max_seq=max_seq, block_size=block,
         prefill_chunk=args.prefill_chunk, psum_mode=args.psum_mode,
         prefill_plan=prefill_plan, decode_plan=decode_plan,
         batched_prefill=not args.no_batched_prefill, check=args.check,
         model_parallel=args.model_parallel)
+    print("[serve] engine set-up (trace, compile + run): "
+          + ", ".join(f"{k} {v:.1f}s" for k, v in engine.setup_s.items()))
+    compile_s = engine.warmup()
+    print("[serve] first call per step (compile + run): "
+          + ", ".join(f"{k} {v:.1f}s" for k, v in compile_s.items()))
 
-    prompts = make_prompts(cfg, args.batch, args.prompt_len)
-    requests = [
-        Request(rid=f"req{i}", prompt_len=args.prompt_len,
-                max_new=args.gen + 1,
-                prompt=tuple(int(t) for t in prompts[i]))
-        for i in range(args.batch)]
-
-    t0 = time.time()
+    t0 = time.perf_counter()
     report = engine.run(requests)
-    dt = time.time() - t0
+    dt = time.perf_counter() - t0
     total = sum(len(r["tokens"]) for r in report.requests)
-    print(f"[serve] engine: {args.batch} requests on {slots} slots, "
-          f"{report.iterations} iterations ({report.prefill_chunks} prefill "
-          f"chunks, {report.decode_steps} decode steps), {total} tokens in "
-          f"{dt*1e3:.0f} ms ({total/dt:.1f} tok/s)")
+    print(f"[serve] engine: {len(requests)} requests on {slots} slots, "
+          f"max_seq {max_seq}, {report.iterations} iterations "
+          f"({report.prefill_chunks} prefill chunks, {report.decode_steps} "
+          f"decode steps), {total} tokens in {dt:.3f} s host wall time")
     by_rid = report.tokens()
-    sample = by_rid["req0"]
-    print(f"[serve] sample req0: {sample}")
+    print(f"[serve] sample {requests[0].rid}: {by_rid[requests[0].rid]}")
     for rid, toks in by_rid.items():
-        assert all(0 <= t < cfg.vocab for t in toks), rid
+        if not all(0 <= t < cfg.vocab for t in toks):
+            raise RuntimeError(f"{rid}: token outside the vocabulary")
+    return engine, report
 
 
 def run_legacy(args, cfg) -> None:
@@ -176,6 +182,7 @@ def run_legacy(args, cfg) -> None:
 
 def main(argv=None) -> None:
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     cfg = ARCHS[args.arch]
     if args.reduced:
         cfg = cfg.reduced()

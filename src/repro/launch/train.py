@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from repro.configs import ARCHS
 from repro.configs.base import ShapeConfig
 from repro.data.pipeline import DataConfig, TokenPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models.api import get_model
 from repro.optim.adamw import adamw_init
@@ -48,6 +49,7 @@ def main() -> None:
     ap.add_argument("--production-mesh", action="store_true",
                     help="16x16 mesh (requires 256 devices)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = ARCHS[args.arch]
     if args.reduced:

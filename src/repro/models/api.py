@@ -194,7 +194,7 @@ def cache_batch_axes(cfg: ModelConfig) -> dict:
     and to slice single requests out of a batched cache
     (``repro.serve.kvcache`` / ``parallel.steps.build_paged_serve_step``).
     """
-    marker = ("__batch__",)
+    marker = "__batch__"    # a one-name entry is stored as the bare name
 
     def axis_of(spec: P) -> int:
         for i, e in enumerate(spec):

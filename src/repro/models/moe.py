@@ -136,7 +136,7 @@ def moe_mlp(p: dict, x: jax.Array, cfg: ModelConfig,
         e_local = e // n_shards
         from jax.sharding import PartitionSpec as P
 
-        from repro.compat import shard_map
+        from jax import shard_map
         from repro.core.collectives import psum_with_mode
 
         def body(xt, gi, po, ke, gv, wg, wu, wd):

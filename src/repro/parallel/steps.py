@@ -167,7 +167,9 @@ class PagedServeStep:
     requests join/leave the batch at token boundaries.  Semantically slot
     ``i`` computes exactly what a ``B=1`` ``decode_step`` at ``pos[i]``
     would (the step is a vmap of the per-request decode), so continuous
-    batching cannot change any request's output.
+    batching cannot change any request's output.  Built with
+    ``logits=True`` the step returns each slot's logits ``[B, V]`` in place
+    of ``next_tok``.
     """
 
     fn: object
@@ -181,7 +183,7 @@ class PagedServeStep:
 def build_paged_serve_step(model: Model, mesh: Mesh, shape: ShapeConfig,
                            pctx: Optional[ParallelCtx] = None,
                            donate_cache: bool = True,
-                           plan=None) -> PagedServeStep:
+                           plan=None, logits: bool = False) -> PagedServeStep:
     """Per-slot-position decode step for the paged-KV serving engine.
 
     ``shape.global_batch`` is the slot count (the continuous-batching
@@ -218,9 +220,11 @@ def build_paged_serve_step(model: Model, mesh: Mesh, shape: ShapeConfig,
         batch = {"tokens": tok[None, :], "pos": pos}
         if media_row is not None:
             batch["media"] = media_row[None]
-        logits, new_cache = model.decode_step(params, batch, cache_b, pctx)
-        nxt = jnp.argmax(logits[0, -1, :], axis=-1)
-        return nxt, jax.tree.map(lambda l, a: jnp.squeeze(l, axis=a),
+        out, new_cache = model.decode_step(params, batch, cache_b, pctx)
+        out = out[0, -1, :]
+        if not logits:
+            out = jnp.argmax(out, axis=-1)
+        return out, jax.tree.map(lambda l, a: jnp.squeeze(l, axis=a),
                                  new_cache, baxis)
 
     if has_media:
@@ -237,7 +241,11 @@ def build_paged_serve_step(model: Model, mesh: Mesh, shape: ShapeConfig,
         def step(params, batch, cache):
             return vmapped(params, batch["tokens"], batch["pos"], cache)
 
-    jitted = jax.jit(step, donate_argnums=(2,) if donate_cache else ())
+    # Per-slot tokens and positions are tiny: replicate them.
+    rep = NamedSharding(mesh, P())
+    jitted = jax.jit(step, in_shardings=(psh, rep, csh),
+                     out_shardings=(rep, csh),
+                     donate_argnums=(2,) if donate_cache else ())
     return PagedServeStep(fn=jitted, param_sharding=psh, cache_sharding=csh,
                           param_shapes=pshapes, cache_shapes=cshapes,
                           cache_batch_axes=baxis)
@@ -289,7 +297,11 @@ def build_prefill_step(model: Model, mesh: Mesh, shape: ShapeConfig,
         return model.prefill(params, {"tokens": batch["tokens"]}, cache,
                              pctx, pos_offset=batch["pos0"])
 
-    jitted = jax.jit(step, donate_argnums=(2,) if donate_cache else ())
+    # One chunk of tokens in, its logits out (read on the host): replicated.
+    rep = NamedSharding(mesh, P())
+    jitted = jax.jit(step, in_shardings=(psh, rep, csh),
+                     out_shardings=(rep, csh),
+                     donate_argnums=(2,) if donate_cache else ())
     return PrefillStep(fn=jitted, chunk=chunk, param_sharding=psh,
                        cache_sharding=csh)
 

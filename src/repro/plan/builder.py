@@ -52,11 +52,8 @@ def trace_mesh(mesh_shape):
     import jax
     if isinstance(mesh_shape, jax.sharding.Mesh):
         return mesh_shape
-    abstract = getattr(jax.sharding, "AbstractMesh", None)
-    if abstract is None:                      # pragma: no cover - old jax
-        raise RuntimeError("planning without a concrete mesh needs "
-                           "jax.sharding.AbstractMesh")
-    return abstract(normalize_mesh(mesh_shape))
+    names, sizes = zip(*normalize_mesh(mesh_shape))
+    return jax.sharding.AbstractMesh(sizes, names)
 
 
 def phase_shape(phase: str, shape: Optional[ShapeConfig] = None,

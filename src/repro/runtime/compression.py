@@ -20,7 +20,7 @@ from typing import Literal, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
+from jax.lax import axis_size
 
 Codec = Literal["none", "int8", "topk"]
 

@@ -70,6 +70,7 @@ class ServingEngine:
                  param_seed: int = 0) -> None:
         import jax
 
+        from repro.exec.timing import Stopwatch
         from repro.launch.mesh import make_host_mesh
         from repro.models.api import get_model
         from repro.parallel.steps import (build_paged_serve_step,
@@ -91,15 +92,20 @@ class ServingEngine:
             num_blocks = slots * math.ceil(self.max_seq / block_size)
         self.kv = PagedKVCache(cfg, self.max_seq, block_size, num_blocks)
         self.sched = Scheduler(slots, self.kv, policy)
+        #: seconds each part of construction took (tracing, compile, run)
+        self.setup_s = {}
 
+        watch = Stopwatch()
         self.mesh = make_host_mesh(model_parallel)
-        pctx_d = ParallelCtx(mesh=self.mesh, psum_mode=psum_mode,
-                             plan=decode_plan)
+        self._pctx_d = ParallelCtx(mesh=self.mesh, psum_mode=psum_mode,
+                                   plan=decode_plan)
         pctx_p = ParallelCtx(mesh=self.mesh, psum_mode=psum_mode,
                              plan=prefill_plan)
-        dshape = ShapeConfig("serve", self.max_seq, slots, "decode")
-        self.step = build_paged_serve_step(self.model, self.mesh, dshape,
-                                           pctx_d, donate_cache=True)
+        self._dshape = ShapeConfig("serve", self.max_seq, slots, "decode")
+        self.step = build_paged_serve_step(self.model, self.mesh,
+                                           self._dshape, self._pctx_d,
+                                           donate_cache=True)
+        self._logits_step = None
         self.baxis = self.step.cache_batch_axes
 
         self.prefill_step = None
@@ -108,17 +114,41 @@ class ServingEngine:
             self.prefill_step = build_prefill_step(
                 self.model, self.mesh, pshape, prefill_chunk, pctx_p,
                 donate_cache=True)
-            self._pcache = self.model.init_cache(1, self.max_seq)
         else:
             # per-token fallback: B=1 decode loop doubles as prefill
             self._loop_step = jax.jit(
                 lambda p, t, pos, c: self.model.decode_step(
                     p, {"tokens": t, "pos": pos}, c, pctx_p))
+        self.setup_s["steps"] = watch.seconds
 
-        self.params = jax.device_put(
-            self.model.init(jax.random.PRNGKey(param_seed)),
-            self.step.param_sharding)
-        self.working = self.model.init_cache(slots, self.max_seq)
+        # Params and caches are made on the device, in the steps' layout:
+        # no host copy, and no staging of the whole model on one device
+        # when the params are sharded.
+        watch = Stopwatch()
+        self.params = jax.block_until_ready(jax.jit(
+            self.model.init, out_shardings=self.step.param_sharding)(
+                jax.random.PRNGKey(param_seed)))
+        self.setup_s["params"] = watch.seconds
+        watch = Stopwatch()
+        csh = self.step.cache_sharding
+        self.working = jax.jit(
+            lambda: self.model.init_cache(slots, self.max_seq),
+            out_shardings=csh)()
+        if self.prefill_step is not None:
+            self._pcache = jax.jit(
+                lambda: self.model.init_cache(1, self.max_seq),
+                out_shardings=self.prefill_step.cache_sharding)()
+            jax.block_until_ready(self._pcache)
+        jax.block_until_ready(self.working)
+        self.setup_s["caches"] = watch.seconds
+
+        def seat(working, row, slot):
+            return jax.tree.map(
+                lambda leaf, r, a: jax.lax.dynamic_update_index_in_dim(
+                    leaf, r.astype(leaf.dtype), slot, a),
+                working, row, self.baxis)
+
+        self._seat_fn = jax.jit(seat, out_shardings=csh, donate_argnums=0)
         self._jnp = jax.numpy
         self._jax = jax
 
@@ -133,14 +163,82 @@ class ServingEngine:
     def _seat(self, st: RequestState) -> None:
         """Materialize the request's pooled row into its working-cache
         slot (zeros past its length — masked by decode attention)."""
-        jnp = self._jnp
         row = self.kv.gather_row(st.req.rid, st.req.prompt_len)
+        self.working = self._seat_fn(self.working, row,
+                                     self._jnp.asarray(st.slot, np.int32))
 
-        def put(leaf, r, a, slot=st.slot):
-            idx = (slice(None),) * a + (slot,)
-            return leaf.at[idx].set(jnp.asarray(r, dtype=leaf.dtype))
+    def prefill_logits(self, prompt):
+        """Prefill ``prompt`` (token ids) chunk by chunk into the prefill
+        cache; returns the last chunk's logits ``[chunk, V]`` (rows past
+        the prompt's end are padding).  Needs the batched prefill step."""
+        jnp = self._jnp
+        prompt = np.asarray(prompt, np.int32)
+        chunk = self.prefill_chunk
+        logits = None
+        for c0 in range(0, len(prompt), chunk):
+            part = prompt[c0:c0 + chunk]
+            toks = np.zeros((1, chunk), np.int32)
+            toks[0, :len(part)] = part         # pad tail: causally masked
+            logits, self._pcache = self.prefill_step.fn(
+                self.params,
+                {"tokens": jnp.asarray(toks),
+                 "pos0": jnp.asarray(c0, jnp.int32)},
+                self._pcache)
+        return logits[0]
 
-        self.working = self._jax.tree.map(put, self.working, row, self.baxis)
+    def decode_logits(self, prompt):
+        """Prefill ``prompt`` into slot 0, then run one decode step on the
+        token the prefill picked; returns slot 0's logits ``[V]``.  The
+        step is the engine's decode program (same shapes, same psum plan)
+        with the final argmax left off.  Slot 0 is reseated before any
+        request reads it."""
+        from repro.parallel.steps import build_paged_serve_step
+
+        jnp = self._jnp
+        if self._logits_step is None:
+            self._logits_step = build_paged_serve_step(
+                self.model, self.mesh, self._dshape, self._pctx_d,
+                donate_cache=False, logits=True)
+        plen = len(prompt)
+        last = self.prefill_logits(prompt)[(plen - 1) % self.prefill_chunk]
+        self.working = self._seat_fn(self.working,
+                                     self._extract_row(self._pcache, 0),
+                                     jnp.asarray(0, np.int32))
+        toks = np.zeros((self.slots, 1), np.int32)
+        toks[0, 0] = int(jnp.argmax(last))
+        pos = np.zeros((self.slots,), np.int32)
+        pos[0] = plen
+        logits, _ = self._logits_step.fn(
+            self.params, {"tokens": jnp.asarray(toks), "pos": jnp.asarray(pos)},
+            self.working)
+        return logits[0]
+
+    def warmup(self) -> dict:
+        """Compile every step the engine runs; returns the seconds each
+        first call took (compile + one execution).  Leaves no state that
+        serving can see: slots are reseated and prefill rows rewritten
+        before they are read."""
+        from repro.exec.timing import Stopwatch
+
+        jax, jnp = self._jax, self._jnp
+        out = {}
+        if self.prefill_step is not None:
+            watch = Stopwatch()
+            jax.block_until_ready(
+                self.prefill_logits(np.zeros(self.prefill_chunk, np.int32)))
+            out["prefill"] = watch.seconds
+        watch = Stopwatch()
+        _, self.working = jax.block_until_ready(self.step.fn(
+            self.params, {"tokens": jnp.zeros((self.slots, 1), jnp.int32),
+                          "pos": jnp.zeros((self.slots,), jnp.int32)},
+            self.working))
+        out["decode"] = watch.seconds
+        watch = Stopwatch()
+        row = self._extract_row(self.working, 0)
+        self.working = jax.block_until_ready(
+            self._seat_fn(self.working, row, jnp.asarray(0, np.int32)))
+        out["page_out+seat"] = watch.seconds
+        return out
 
     def _prefill(self, st: RequestState) -> tuple[int, int]:
         """Run the prompt, write its K/V into the pool, return (first
@@ -151,19 +249,9 @@ class ServingEngine:
         plen = req.prompt_len
         if self.prefill_step is not None:
             chunk = self.prefill_chunk
-            steps = 0
-            logits = None
-            for c0 in range(0, plen, chunk):
-                part = prompt[c0:c0 + chunk]
-                toks = np.zeros((1, chunk), np.int32)
-                toks[0, :len(part)] = part     # pad tail: causally masked
-                logits, self._pcache = self.prefill_step.fn(
-                    self.params,
-                    {"tokens": jnp.asarray(toks),
-                     "pos0": jnp.asarray(c0, jnp.int32)},
-                    self._pcache)
-                steps += 1
-            first = int(jnp.argmax(logits[0, (plen - 1) % chunk]))
+            logits = self.prefill_logits(prompt)
+            steps = -(-plen // chunk)
+            first = int(jnp.argmax(logits[(plen - 1) % chunk]))
             row = self._extract_row(self._pcache, 0)
         else:
             cache = self.model.init_cache(1, self.max_seq)

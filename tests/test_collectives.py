@@ -19,7 +19,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 from functools import partial
 
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.core.collectives import (per_link_bytes, psum_ina, psum_with_mode,
                                     reduce_scatter_with_mode,

@@ -138,3 +138,36 @@ def test_wkv6_decay_extremes():
         want = ref.wkv6_ref(r, k, v, logw, u)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-4, atol=1e-4)
+
+
+# --------------------------------------------------------------------------- #
+# dispatch (kernels/ops.py)
+# --------------------------------------------------------------------------- #
+def test_ops_dispatch_never_interprets_unasked(monkeypatch):
+    """Off a TPU: ``use_pallas=True`` raises, ``interpret=True`` runs the
+    kernel in the interpreter, the default takes the jnp reference."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_on_tpu", lambda: False)
+    ks = jax.random.split(KEY, 5)
+    x = jax.random.normal(ks[0], (128, 256), jnp.float32)
+    w = jax.random.normal(ks[1], (256, 128), jnp.float32)
+    q, k, v = (jax.random.normal(kk, (2, 128, 64), jnp.float32)
+               for kk in ks[2:])
+    logw = -jnp.exp(q * 0.5 - 1.0)
+    u = jnp.full((2, 64), 0.1)
+    calls = {
+        "matmul": (lambda **kw: ops.matmul(x, w, bm=128, bn=128, bk=128,
+                                           **kw), ref.matmul_ref(x, w)),
+        "attention": (lambda **kw: ops.attention(q, k, v, bq=128, bkv=128,
+                                                 **kw),
+                      ref.attention_ref(q, k, v)),
+        "wkv": (lambda **kw: ops.wkv(q, k, v, logw, u, chunk=64, **kw),
+                ref.wkv6_ref(q, k, v, logw, u)),
+    }
+    for name, (call, want) in calls.items():
+        with pytest.raises(RuntimeError, match="needs a TPU"):
+            call(use_pallas=True)
+        for kw in ({"interpret": True}, {}):
+            np.testing.assert_allclose(np.asarray(call(**kw)),
+                                       np.asarray(want), rtol=1e-4,
+                                       atol=1e-4, err_msg=f"{name} {kw}")

@@ -405,7 +405,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.collectives import choose_psum_mode, psum_with_mode
 from repro.plan import ExecutionPlan, PsumDecision
 

@@ -171,10 +171,11 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from repro.runtime.compression import CompressionState, compressed_psum
 
-mesh = jax.make_mesh((8,), ("pod",))
+mesh = make_mesh((8,), ("pod",))
 g = jax.random.normal(jax.random.PRNGKey(0), (8, 64, 32)) * 0.01
 ref = g.mean(axis=0)
 

@@ -1,0 +1,70 @@
+"""The Pallas kernels compile for a described TPU v5e at model widths.
+
+Interpret mode accepts kernels that the chip's compiler (Mosaic) refuses,
+so each kernel is compiled here for a v5e that is described, not attached:
+``ina_matmul`` and ``flash_attention`` at qwen2-1.5b widths, ``wkv6`` at
+rwkv6-7b widths.  Only shapes are needed; nothing runs.
+
+The topology is described inside a module-scoped fixture, never while a
+module is imported: only one process may load the TPU library, and under
+pytest-xdist every worker imports every test file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.ina_matmul import ina_matmul
+from repro.kernels.wkv6 import wkv6
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    with pytest.MonkeyPatch.context() as mp:
+        # keep the TPU compiler's logs out of the temp directory
+        mp.setenv("TPU_LOG_DIR", os.environ.get("TPU_LOG_DIR", "disabled"))
+        # a compile for a described chip can be written to the persistent
+        # cache but never read back: keep the cache out of it
+        enabled = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        try:
+            try:
+                yield topologies.get_topology_desc(platform="tpu",
+                                                   topology_name="v5e:2x2")
+            except Exception as e:                          # noqa: BLE001
+                pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        finally:
+            jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _kernel_case(name):
+    """(kernel call, argument shapes) at the widths the models use."""
+    if name == "ina_matmul":      # qwen2-1.5b MLP up-projection, 2048 tokens
+        return (lambda x, w: ina_matmul(x, w),
+                [((2048, 1536), BF16), ((1536, 8960), BF16)])
+    if name == "flash_attention":  # qwen2-1.5b: 12 heads x 4096 x 128
+        return (lambda q, k, v: flash_attention(q, k, v),
+                [((12, 4096, 128), BF16)] * 3)
+    if name == "wkv6":            # rwkv6-7b: 64 heads x 4096 x 64
+        return (lambda r, k, v, w, u: wkv6(r, k, v, w, u),
+                [((64, 4096, 64), BF16)] * 4 + [((64, 64), BF16)])
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("name", ["ina_matmul", "flash_attention", "wkv6"])
+def test_kernel_compiles_for_v5e(one_chip, name):
+    fn, shapes = _kernel_case(name)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo, f"{name} did not lower to a Mosaic kernel"

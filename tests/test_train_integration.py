@@ -23,10 +23,11 @@ from repro.optim.adamw import adamw_init
 from repro.parallel.steps import build_serve_step, build_train_step
 from repro.parallel.tp import ParallelCtx
 from repro.data.pipeline import DataConfig, TokenPipeline
+from repro.launch.mesh import make_host_mesh
 
 cfg = ARCHS["qwen2-1.5b"].reduced()
 model = get_model(cfg)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_host_mesh(4)            # (data 2, model 4)
 shape = ShapeConfig("t", 64, 4, "train")
 pipe = TokenPipeline(DataConfig(vocab=cfg.vocab, seq_len=64, global_batch=4))
 batch = pipe.batch(0)
@@ -72,7 +73,7 @@ from repro.models.api import param_specs
 
 d = tempfile.mkdtemp()
 save_pytree(params, d, 5)
-mesh2 = jax.make_mesh((4, 2), ("data", "model"))
+mesh2 = make_host_mesh(2)           # (data 4, model 2)
 restored, step = elastic_restore(
     jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params),
     d, mesh2, lambda t, m: param_specs(t, m))
