@@ -105,14 +105,22 @@ def run_engine(args, cfg, requests=None):
     print("[serve] first call per step (compile + run): "
           + ", ".join(f"{k} {v:.1f}s" for k, v in compile_s.items()))
 
-    t0 = time.perf_counter()
+    rec = engine.recorder
+    t0, paged = rec.clock(), rec.counters.get("page_out_bytes", 0)
     report = engine.run(requests)
-    dt = time.perf_counter() - t0
+    wall = rec.clock() - t0
+    paged = rec.counters.get("page_out_bytes", 0) - paged
     total = sum(len(r["tokens"]) for r in report.requests)
     print(f"[serve] engine: {len(requests)} requests on {slots} slots, "
           f"max_seq {max_seq}, {report.iterations} iterations "
           f"({report.prefill_chunks} prefill chunks, {report.decode_steps} "
-          f"decode steps), {total} tokens in {dt:.3f} s host wall time")
+          f"decode steps), {total} tokens in {wall:.3f} s host wall time")
+    stalls = [s.end - s.start for s in rec.spans() if s.name == "admit"
+              and s.start >= t0 and (s.counts or {}).get("admitted")]
+    print(f"[serve] page-out "
+          f"{paged / max(1, report.decode_steps) / 1e6:.3f} MB per decode "
+          f"step; admission stall {1e3 * sum(stalls) / max(1, len(stalls)):.1f}"
+          f" ms mean over {len(stalls)} admissions")
     by_rid = report.tokens()
     print(f"[serve] sample {requests[0].rid}: {by_rid[requests[0].rid]}")
     for rid, toks in by_rid.items():

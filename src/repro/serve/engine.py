@@ -12,9 +12,27 @@ they share :mod:`~repro.serve.batching` and the block-accounting rules of
    prompt's K/V into the paged pool and emitting the first token,
 3. runs one vmapped per-slot-position decode step
    (:func:`~repro.parallel.steps.build_paged_serve_step`) over the whole
-   slot batch, appends one token per active request, and pages out the
-   newly written cache column,
+   slot batch, appends one token per active request, pages out each
+   active slot's cache row and writes its new position into the pool,
 4. retires finished requests, releasing their blocks and slot.
+
+Each of these is a span of the engine's :class:`~repro.exec.trace.Recorder`
+(``engine.recorder``), with counters at the same boundaries:
+
+* ``decode`` (one per iteration) holds ``decode.step`` (the step program
+  up to its tokens on the host), a ``page_out`` and a ``pool_write`` per
+  active row, and ``retire``;
+* ``admit`` (one per iteration; the ``admitted`` count where it admits)
+  holds a ``prefill`` per request: ``prefill.chunks`` (up to the first
+  token on the host), then ``page_out`` and ``pool_write`` of the prefill
+  row, and ``seat``;
+* counters ``page_out_bytes`` (the host arrays' ``nbytes``),
+  ``page_out_rows``, ``prefill_chunks``, ``decode_steps``,
+  ``active_slot_steps`` and ``admitted``.
+
+The engine calls ``self.sched.admit``, ``self.prefill_step.fn`` and
+``self.step.fn`` by attribute at call time, so wrappers put there sit
+inside these spans.
 
 Each slot computes exactly what the request would compute running alone
 (the decode step is a vmap of the B=1 decode; decode attention masks
@@ -71,6 +89,7 @@ class ServingEngine:
         import jax
 
         from repro.exec.timing import Stopwatch
+        from repro.exec.trace import Recorder
         from repro.launch.mesh import make_host_mesh
         from repro.models.api import get_model
         from repro.parallel.steps import (build_paged_serve_step,
@@ -94,6 +113,8 @@ class ServingEngine:
         self.sched = Scheduler(slots, self.kv, policy)
         #: seconds each part of construction took (tracing, compile, run)
         self.setup_s = {}
+        #: spans and counters of serving (not of construction or warm-up)
+        self.recorder = Recorder()
 
         watch = Stopwatch()
         self.mesh = make_host_mesh(model_parallel)
@@ -160,12 +181,27 @@ class ServingEngine:
             lambda leaf, a: np.asarray(jnp.take(leaf, slot, axis=a)),
             cache, self.baxis)
 
+    def _page_out(self, cache, slot: int, rid):
+        """``_extract_row`` as a ``page_out`` span, counted."""
+        rec = self.recorder
+        with rec.span("page_out", rid=rid):
+            row = self._extract_row(cache, slot)
+            rec.count("page_out_rows")
+            rec.count("page_out_bytes",
+                      sum(leaf.nbytes for leaf in self._jax.tree.leaves(row)))
+        return row
+
+    def _pool_write(self, rid, pos0: int, row, length: int) -> None:
+        with self.recorder.span("pool_write", rid=rid):
+            self.kv.write_range(rid, pos0, row, length)
+
     def _seat(self, st: RequestState) -> None:
         """Materialize the request's pooled row into its working-cache
         slot (zeros past its length — masked by decode attention)."""
-        row = self.kv.gather_row(st.req.rid, st.req.prompt_len)
-        self.working = self._seat_fn(self.working, row,
-                                     self._jnp.asarray(st.slot, np.int32))
+        with self.recorder.span("seat", rid=st.req.rid):
+            row = self.kv.gather_row(st.req.rid, st.req.prompt_len)
+            self.working = self._seat_fn(self.working, row,
+                                         self._jnp.asarray(st.slot, np.int32))
 
     def prefill_logits(self, prompt):
         """Prefill ``prompt`` (token ids) chunk by chunk into the prefill
@@ -240,31 +276,33 @@ class ServingEngine:
         out["page_out+seat"] = watch.seconds
         return out
 
-    def _prefill(self, st: RequestState) -> tuple[int, int]:
-        """Run the prompt, write its K/V into the pool, return (first
-        generated token, chunk/step count)."""
+    def _prefill(self, st: RequestState) -> None:
+        """Run the prompt, write its K/V into the pool, seat it, and give
+        the request its first generated token."""
         jnp = self._jnp
+        rec = self.recorder
         req = st.req
         prompt = np.asarray(req.prompt, np.int32)
         plen = req.prompt_len
-        if self.prefill_step is not None:
-            chunk = self.prefill_chunk
-            logits = self.prefill_logits(prompt)
-            steps = -(-plen // chunk)
-            first = int(jnp.argmax(logits[(plen - 1) % chunk]))
-            row = self._extract_row(self._pcache, 0)
-        else:
-            cache = self.model.init_cache(1, self.max_seq)
-            steps = 0
-            for pos in range(plen):
-                lg, cache = self._loop_step(
-                    self.params, jnp.asarray(prompt[None, pos:pos + 1]),
-                    jnp.asarray(pos, jnp.int32), cache)
-                steps += 1
-            first = int(jnp.argmax(lg[0, -1]))
-            row = self._extract_row(cache, 0)
-        self.kv.write_range(req.rid, 0, row, plen)
-        return first, steps
+        with rec.span("prefill.chunks", rid=req.rid):
+            if self.prefill_step is not None:
+                chunk = self.prefill_chunk
+                logits = self.prefill_logits(prompt)
+                rec.count("prefill_chunks", -(-plen // chunk))
+                first = int(jnp.argmax(logits[(plen - 1) % chunk]))
+                cache = self._pcache
+            else:
+                cache = self.model.init_cache(1, self.max_seq)
+                for pos in range(plen):
+                    lg, cache = self._loop_step(
+                        self.params, jnp.asarray(prompt[None, pos:pos + 1]),
+                        jnp.asarray(pos, jnp.int32), cache)
+                rec.count("prefill_chunks", plen)
+                first = int(jnp.argmax(lg[0, -1]))
+        row = self._page_out(cache, 0, req.rid)
+        self._pool_write(req.rid, 0, row, plen)
+        self._seat(st)
+        st.generated.append(first)
 
     # ------------------------------------------------------------------ #
     def run(self, requests: list[Request], max_iters: int = 100_000,
@@ -279,17 +317,20 @@ class ServingEngine:
                                  f"{self.max_seq}")
             self.sched.submit(req)
 
-        finished, it, pf_chunks, dsteps, checks = [], 0, 0, 0, 0
+        rec = self.recorder
+        before = dict(rec.counters)
+        finished, it, checks = [], 0, 0
         while self.sched.has_work:
             if it >= max_iters:
                 raise RuntimeError(f"engine exceeded {max_iters} iterations")
-            admitted = self.sched.admit(now=it)
-            for st in admitted:
-                first, steps = self._prefill(st)
-                pf_chunks += steps
-                self._seat(st)
-                st.generated.append(first)
-                st.first_token_time = it
+            with rec.span("admit"):
+                admitted = self.sched.admit(now=it)
+                if admitted:
+                    rec.count("admitted", len(admitted))
+                for st in admitted:
+                    with rec.span("prefill", rid=st.req.rid):
+                        self._prefill(st)
+                    st.first_token_time = it
             if not self.sched.active:
                 if len(self.sched.queue):
                     head = self.sched.queue.peek()
@@ -303,50 +344,56 @@ class ServingEngine:
                 it += 1
                 continue
 
-            toks = np.zeros((self.slots, 1), np.int32)
-            pos = np.zeros((self.slots,), np.int32)
-            for slot, st in self.sched.active.items():
-                toks[slot, 0] = st.generated[-1]
-                pos[slot] = st.pos - 1           # feed token at its position
-            nxt, self.working = self.step.fn(
-                self.params,
-                {"tokens": jnp.asarray(toks), "pos": jnp.asarray(pos)},
-                self.working)
-            nxt = np.asarray(nxt)
-            dsteps += 1
-            for slot, st in list(self.sched.active.items()):
-                written = st.pos - 1
-                row = self._extract_row(self.working, slot)
-                self.kv.write_range(st.req.rid, written, row, 1)
-                st.generated.append(int(nxt[slot]))
-            it += 1
-            checks += self._retire(it, finished)
+            with rec.span("decode"):
+                toks = np.zeros((self.slots, 1), np.int32)
+                pos = np.zeros((self.slots,), np.int32)
+                for slot, st in self.sched.active.items():
+                    toks[slot, 0] = st.generated[-1]
+                    pos[slot] = st.pos - 1       # feed token at its position
+                with rec.span("decode.step"):
+                    nxt, self.working = self.step.fn(
+                        self.params,
+                        {"tokens": jnp.asarray(toks), "pos": jnp.asarray(pos)},
+                        self.working)
+                    nxt = np.asarray(nxt)
+                    rec.count("decode_steps")
+                    rec.count("active_slot_steps", len(self.sched.active))
+                for slot, st in list(self.sched.active.items()):
+                    written = st.pos - 1
+                    row = self._page_out(self.working, slot, st.req.rid)
+                    self._pool_write(st.req.rid, written, row, 1)
+                    st.generated.append(int(nxt[slot]))
+                it += 1
+                checks += self._retire(it, finished)
         self.kv.check()
+        done = {k: v - before.get(k, 0) for k, v in rec.counters.items()}
         return EngineReport(requests=finished, iterations=it,
-                            prefill_chunks=pf_chunks, decode_steps=dsteps,
+                            prefill_chunks=done.get("prefill_chunks", 0),
+                            decode_steps=done.get("decode_steps", 0),
                             checks=checks)
 
     def _retire(self, it: int, finished: list) -> int:
         checks = 0
-        for slot in sorted(self.sched.active):
-            st = self.sched.active[slot]
-            if not st.done:
-                continue
-            if self.check:
-                # every position actually fed is pooled bit-identically
-                covered = st.req.prompt_len + len(st.generated) - 1
-                self.kv.assert_matches(
-                    st.req.rid, self._extract_row(self.working, slot),
-                    min(covered, self.max_seq))
-                self.kv.check()
-                checks += 1
-            self.sched.finish(slot, now=it)
-            finished.append({
-                "rid": st.req.rid, "slot": slot,
-                "prompt_len": st.req.prompt_len,
-                "tokens": list(st.generated),
-                "admit_iter": int(st.admit_time),
-                "first_token_iter": int(st.first_token_time),
-                "finish_iter": it,
-            })
+        with self.recorder.span("retire"):
+            for slot in sorted(self.sched.active):
+                st = self.sched.active[slot]
+                if not st.done:
+                    continue
+                if self.check:
+                    # every position actually fed is pooled bit-identically
+                    covered = st.req.prompt_len + len(st.generated) - 1
+                    self.kv.assert_matches(
+                        st.req.rid, self._extract_row(self.working, slot),
+                        min(covered, self.max_seq))
+                    self.kv.check()
+                    checks += 1
+                self.sched.finish(slot, now=it)
+                finished.append({
+                    "rid": st.req.rid, "slot": slot,
+                    "prompt_len": st.req.prompt_len,
+                    "tokens": list(st.generated),
+                    "admit_iter": int(st.admit_time),
+                    "first_token_iter": int(st.first_token_time),
+                    "finish_iter": it,
+                })
         return checks
