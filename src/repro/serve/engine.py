@@ -12,22 +12,29 @@ they share :mod:`~repro.serve.batching` and the block-accounting rules of
    prompt's K/V into the paged pool and emitting the first token,
 3. runs one vmapped per-slot-position decode step
    (:func:`~repro.parallel.steps.build_paged_serve_step`) over the whole
-   slot batch, appends one token per active request, pages out each
-   active slot's cache row and writes its new position into the pool,
+   slot batch, appends one token per active request, and pages out the
+   cache column the step wrote: one gather program
+   (:func:`gather_columns`) takes each slot's entry at its
+   position (whole state for leaves without a sequence axis) into one
+   ``[slots, n]`` array per dtype, which comes to the host in one
+   transfer; each active slot's column is written into the pool at its
+   position,
 4. retires finished requests, releasing their blocks and slot.
 
 Each of these is a span of the engine's :class:`~repro.exec.trace.Recorder`
 (``engine.recorder``), with counters at the same boundaries:
 
-* ``decode`` (one per iteration) holds ``decode.step`` (the step program
-  up to its tokens on the host), a ``page_out`` and a ``pool_write`` per
-  active row, and ``retire``;
+* ``decode`` (one per iteration) holds ``decode.step`` (the step program,
+  with the column gather dispatched behind it, up to the tokens on the
+  host), one ``page_out`` (no rid: the wait on the columns and their
+  transfer), a ``pool_write`` per active row, and ``retire``;
 * ``admit`` (one per iteration; the ``admitted`` count where it admits)
   holds a ``prefill`` per request: ``prefill.chunks`` (up to the first
   token on the host), then ``page_out`` and ``pool_write`` of the prefill
   row, and ``seat``;
-* counters ``page_out_bytes`` (the host arrays' ``nbytes``),
-  ``page_out_rows``, ``prefill_chunks``, ``decode_steps``,
+* counters ``page_out_bytes`` (the host arrays' ``nbytes``: every slot's
+  column in decode, the whole row in prefill), ``page_out_rows`` (active
+  rows in decode, one per prefill), ``prefill_chunks``, ``decode_steps``,
   ``active_slot_steps`` and ``admitted``.
 
 The engine calls ``self.sched.admit``, ``self.prefill_step.fn`` and
@@ -52,6 +59,7 @@ loop in ``launch/serve.py`` still serves those.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Optional
 
@@ -78,6 +86,37 @@ class EngineReport:
         return {r["rid"]: r["tokens"] for r in self.requests}
 
 
+def gather_columns(working, pos, leaves):
+    """The cache column each decode step wrote, for every slot.
+
+    ``leaves`` is the pool's leaf layout (:attr:`PagedKVCache.leaves`). A
+    leaf with a sequence axis (which follows the batch axis) gives each
+    slot's entry at its ``pos``, one slice per slot: a vmapped gather
+    would relayout the whole cache.  One without gives each slot's whole
+    state.  The columns of one dtype come out as one ``[slots, n]`` array,
+    so one transfer brings them to the host.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    def column(leaf, a, slot, p):
+        start, size = [0] * leaf.ndim, list(leaf.shape)
+        start[a], start[a + 1] = slot, p
+        size[a] = size[a + 1] = 1
+        return jax.lax.dynamic_slice(leaf, start, size).reshape(-1)
+
+    slots = pos.shape[0]
+    packed = {}
+    for leaf, m in zip(jax.tree.leaves(working), leaves):
+        a = m.batch_axis
+        if m.paged:
+            col = jnp.stack([column(leaf, a, i, pos[i]) for i in range(slots)])
+        else:
+            col = jnp.moveaxis(leaf, a, 0).reshape(slots, -1)
+        packed.setdefault(m.dtype.name, []).append(col)
+    return {d: jnp.concatenate(cols, axis=1) for d, cols in packed.items()}
+
+
 class ServingEngine:
     def __init__(self, cfg: ModelConfig, *, slots: int = 4,
                  max_seq: Optional[int] = None, block_size: int = 16,
@@ -87,6 +126,7 @@ class ServingEngine:
                  model_parallel: int = 1, check: bool = False,
                  param_seed: int = 0) -> None:
         import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
 
         from repro.exec.timing import Stopwatch
         from repro.exec.trace import Recorder
@@ -170,6 +210,12 @@ class ServingEngine:
                 working, row, self.baxis)
 
         self._seat_fn = jax.jit(seat, out_shardings=csh, donate_argnums=0)
+
+        # fixed at ``slots`` (inactive slots gather position 0): one compile
+        self._columns = jax.jit(
+            functools.partial(gather_columns, leaves=self.kv.leaves),
+            in_shardings=(csh, NamedSharding(self.mesh, P())))
+        self._cache_def = jax.tree.structure(self.baxis)
         self._jnp = jax.numpy
         self._jax = jax
 
@@ -194,6 +240,18 @@ class ServingEngine:
     def _pool_write(self, rid, pos0: int, row, length: int) -> None:
         with self.recorder.span("pool_write", rid=rid):
             self.kv.write_range(rid, pos0, row, length)
+
+    def _unpack_columns(self, packed) -> list:
+        """:func:`gather_columns`' host arrays as one ``[slots, ...]``
+        array per cache leaf, in the pool's leaf order.  Inactive slots
+        hold position 0's entry, which nothing writes."""
+        out, offset = [], dict.fromkeys(packed, 0)
+        for m in self.kv.leaves:
+            o, n = offset[m.dtype.name], math.prod(m.column_shape)
+            out.append(packed[m.dtype.name][:, o:o + n].reshape(
+                (self.slots,) + m.column_shape))
+            offset[m.dtype.name] = o + n
+        return out
 
     def _seat(self, st: RequestState) -> None:
         """Materialize the request's pooled row into its working-cache
@@ -269,6 +327,10 @@ class ServingEngine:
                           "pos": jnp.zeros((self.slots,), jnp.int32)},
             self.working))
         out["decode"] = watch.seconds
+        watch = Stopwatch()
+        jax.device_get(self._columns(
+            self.working, jnp.zeros((self.slots,), jnp.int32)))
+        out["columns"] = watch.seconds
         watch = Stopwatch()
         row = self._extract_row(self.working, 0)
         self.working = jax.block_until_ready(
@@ -347,21 +409,31 @@ class ServingEngine:
             with rec.span("decode"):
                 toks = np.zeros((self.slots, 1), np.int32)
                 pos = np.zeros((self.slots,), np.int32)
-                for slot, st in self.sched.active.items():
+                active = list(self.sched.active.items())
+                for slot, st in active:
                     toks[slot, 0] = st.generated[-1]
                     pos[slot] = st.pos - 1       # feed token at its position
                 with rec.span("decode.step"):
+                    pos = jnp.asarray(pos)
                     nxt, self.working = self.step.fn(
-                        self.params,
-                        {"tokens": jnp.asarray(toks), "pos": jnp.asarray(pos)},
+                        self.params, {"tokens": jnp.asarray(toks), "pos": pos},
                         self.working)
+                    packed = self._columns(self.working, pos)
                     nxt = np.asarray(nxt)
                     rec.count("decode_steps")
-                    rec.count("active_slot_steps", len(self.sched.active))
-                for slot, st in list(self.sched.active.items()):
-                    written = st.pos - 1
-                    row = self._page_out(self.working, slot, st.req.rid)
-                    self._pool_write(st.req.rid, written, row, 1)
+                    rec.count("active_slot_steps", len(active))
+                with rec.span("page_out"):
+                    packed = self._jax.device_get(packed)
+                    rec.count("page_out_rows", len(active))
+                    rec.count("page_out_bytes",
+                              sum(a.nbytes for a in packed.values()))
+                    cols = self._unpack_columns(packed)
+                for slot, st in active:
+                    with rec.span("pool_write", rid=st.req.rid):
+                        self.kv.write_column(
+                            st.req.rid, st.pos - 1,
+                            self._cache_def.unflatten(
+                                [c[slot] for c in cols]))
                     st.generated.append(int(nxt[slot]))
                 it += 1
                 checks += self._retire(it, finished)

@@ -112,6 +112,15 @@ class _LeafMeta:
     row_shape: tuple       # shape with the batch axis removed
     dtype: object
 
+    @property
+    def column_shape(self) -> tuple:
+        """One position of a paged row (sequence axis removed); the whole
+        row of an unpaged one."""
+        if not self.paged:
+            return self.row_shape
+        a = self.batch_axis
+        return self.row_shape[:a] + self.row_shape[a + 1:]
+
 
 def _flatten_with_names(tree):
     import jax
@@ -160,9 +169,8 @@ class PagedKVCache:
             self.leaves.append(_LeafMeta(name, a, paged, row,
                                          np.dtype(leaf.dtype)))
             if paged:
-                per_pos = row[:a] + row[a + 1:]
                 self._pools.append(np.zeros(
-                    (num_blocks, block_size) + per_pos,
+                    (num_blocks, block_size) + self.leaves[-1].column_shape,
                     dtype=np.dtype(leaf.dtype)))
             else:
                 self._pools.append(None)
@@ -214,6 +222,20 @@ class PagedKVCache:
                 self._pools[i][table[blk], off] = sf[pos]
         self._length[rid] = max(self._length[rid], pos0 + length)
 
+    def write_column(self, rid, pos: int, column_tree) -> None:
+        """Store position ``pos`` from ``column_tree``: a row's entry at
+        that position on paged leaves (sequence axis removed), the whole
+        per-request state on unpaged ones (as :meth:`write_range`)."""
+        table = self.allocator.tables[rid]
+        blk, off = divmod(pos, self.block_size)
+        cols = self._treedef.flatten_up_to(column_tree)
+        for i, (meta, col) in enumerate(zip(self.leaves, cols)):
+            if meta.paged:
+                self._pools[i][table[blk], off] = col
+            else:
+                self._state[rid][i] = np.array(col)
+        self._length[rid] = max(self._length[rid], pos + 1)
+
     def gather_row(self, rid, length: int | None = None):
         """Reassemble ``rid``'s row (native layout): block contents for
         positions < length, zeros beyond (exactly the monolithic slot)."""
@@ -226,9 +248,7 @@ class PagedKVCache:
                 out.append(np.zeros(meta.row_shape, meta.dtype)
                            if st is None else st.copy())
                 continue
-            per_pos = meta.row_shape[:meta.batch_axis] + \
-                meta.row_shape[meta.batch_axis + 1:]
-            sf = np.zeros((self.max_seq,) + per_pos, meta.dtype)
+            sf = np.zeros((self.max_seq,) + meta.column_shape, meta.dtype)
             for pos in range(length):
                 blk, off = divmod(pos, self.block_size)
                 sf[pos] = self._pools[i][table[blk], off]

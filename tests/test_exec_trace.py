@@ -1,5 +1,7 @@
 """The span-and-counter recorder (``repro.exec.trace``) and the spans the
 serving engine records with it."""
+import math
+
 import numpy as np
 import pytest
 
@@ -114,6 +116,12 @@ def served():
     return eng, report, eng.recorder.spans(), row_bytes
 
 
+def _column_bytes(eng):
+    """Bytes of one slot's column: one position of each paged leaf."""
+    return sum(math.prod(m.row_shape) // eng.max_seq * m.dtype.itemsize
+               for m in eng.kv.leaves if m.paged)
+
+
 def test_every_decode_iteration_pages_out_each_active_row(served):
     eng, report, spans, _ = served
     c = eng.recorder.counters
@@ -129,9 +137,13 @@ def test_every_decode_iteration_pages_out_each_active_row(served):
         n = step.counts["active_slot_steps"]
         active += n
         assert 1 <= n <= eng.slots
-        assert names.count("page_out") == names.count("pool_write") == n
+        # one column transfer for all slots, then a pool write per row
+        po, = [c for c in kids[d.id] if c.name == "page_out"]
+        assert po.rid is None and po.counts["page_out_rows"] == n
+        assert po.counts["page_out_bytes"] == eng.slots * _column_bytes(eng)
+        assert names.count("pool_write") == n
         assert names.count("retire") == 1 and d.parent is None
-        assert len({c.rid for c in kids[d.id] if c.name == "page_out"}) == n
+        assert len({c.rid for c in kids[d.id] if c.name == "pool_write"}) == n
     assert active == c["active_slot_steps"]
 
 
@@ -140,7 +152,11 @@ def test_page_out_bytes_are_rows_times_the_row(served):
     c = eng.recorder.counters
     rows = c["active_slot_steps"] + c["admitted"]    # decode + prefill rows
     assert c["page_out_rows"] == rows
-    assert c["page_out_bytes"] == rows * row_bytes
+    # decode: every slot's column each step; prefill: the whole row
+    assert c["page_out_bytes"] == \
+        c["decode_steps"] * eng.slots * _column_bytes(eng) + \
+        c["admitted"] * row_bytes
+    assert _column_bytes(eng) * eng.max_seq == row_bytes
     assert c["admitted"] == len(_requests())
     assert c["prefill_chunks"] == report.prefill_chunks == \
         sum(-(-r.prompt_len // 4) for r in _requests())

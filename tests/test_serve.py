@@ -14,6 +14,8 @@ Coverage map (ISSUE 6):
   block release on finish;
 * request validation (engine needs prompts; footprint must fit).
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -119,6 +121,52 @@ def test_loop_prefill_matches_batched(reference_tokens):
                         prefill_chunk=4, batched_prefill=False, check=True)
     rep = eng.run(_requests(prompts)[:1])
     assert rep.tokens()["r0"] == ref[0].tolist()
+
+
+# one config per kind of cache leaf the decode page-out gathers: paged
+# K/V; paged K/V beside unpaged conv/SSM state; all unpaged; paged MLA
+# latents and rope keys
+LEAF_KINDS = {"qwen2-1.5b": {True}, "zamba2-2.7b": {True, False},
+              "rwkv6-7b": {False}, "deepseek-v2-lite-16b": {True}}
+
+
+def _mixed_requests(cfg, lens):
+    rng = np.random.default_rng(11)
+    return [Request(rid=f"m{i}", prompt_len=p, max_new=n,
+                    prompt=tuple(int(t) for t in rng.integers(3, cfg.vocab, p)))
+            for i, (p, n) in enumerate(lens)]
+
+
+@pytest.mark.parametrize("arch,dtype",
+                         [(a, "float32") for a in sorted(LEAF_KINDS)]
+                         + [("zamba2-2.7b", "bfloat16")])
+def test_engine_pool_matches_working_cache(arch, dtype):
+    """check=True: at every retirement the request's pooled row (prefill
+    row, then one decode column a step) is bit-identical to its slot of
+    the working cache, for every kind of cache leaf, and for a cache of
+    two dtypes (bf16 K/V beside f32 state, as zamba2 serves)."""
+    cfg = dataclasses.replace(ARCHS[arch].reduced(), dtype=dtype)
+    eng = ServingEngine(cfg, slots=2, max_seq=24, block_size=4,
+                        prefill_chunk=4, check=True)
+    assert {m.paged for m in eng.kv.leaves} == LEAF_KINDS[arch]
+    assert len({m.dtype for m in eng.kv.leaves}) == (
+        2 if dtype == "bfloat16" else 1)
+    reqs = _mixed_requests(cfg, [(5, 6), (9, 4), (3, 7)])
+    rep = eng.run(reqs)
+    assert rep.checks == len(reqs)
+    assert rep.decode_steps >= max(r.max_new for r in reqs)
+
+
+def test_warmup_compiles_the_column_gather():
+    """After warm-up, serving adds no entry to the gather's jit cache:
+    no compile falls inside a timed window."""
+    eng = ServingEngine(ARCH, slots=2, max_seq=24, block_size=4,
+                        prefill_chunk=4)
+    eng.warmup()
+    compiled = eng._columns._cache_size()
+    assert compiled == 1
+    eng.run(_mixed_requests(ARCH, [(5, 3), (7, 4), (2, 2)]))
+    assert eng._columns._cache_size() == compiled
 
 
 def test_engine_rejects_promptless_and_oversized():
@@ -231,6 +279,37 @@ def test_paged_roundtrip_bit_identical(kv, seed, len_a, len_b):
         for rid in list(kv.allocator.tables):
             kv.release(rid)
     assert kv.allocator.free_blocks == 12
+
+
+def test_column_writes_equal_one_range_write():
+    """Writing a row's columns position by position (as decode does)
+    gives the same pool as one write_range of the whole row, on paged
+    and unpaged leaves alike."""
+    cfg = ARCHS["zamba2-2.7b"].reduced()
+    by_range, by_column = (PagedKVCache(cfg, max_seq=16, block_size=4,
+                                        num_blocks=8) for _ in range(2))
+    assert {m.paged for m in by_range.leaves} == {True, False}
+    rng = np.random.default_rng(4)
+    by_range.admit("x", 2)          # another request's blocks come first
+    by_column.admit("x", 2)
+    row = _random_row(by_range, rng)
+    length = 11
+    by_range.admit("a", length)
+    by_column.admit("a", length)
+    by_range.write_range("a", 0, row, length)
+    rows = by_column._treedef.flatten_up_to(row)
+    for pos in range(length):
+        col = [np.take(leaf, pos, axis=m.batch_axis) if m.paged else leaf
+               for m, leaf in zip(by_column.leaves, rows)]
+        by_column.write_column("a", pos, by_column._treedef.unflatten(col))
+    assert by_column.length("a") == by_range.length("a") == length
+    for mine, theirs in zip(by_column._pools, by_range._pools):
+        if mine is not None:
+            np.testing.assert_array_equal(mine, theirs)
+    for mine, theirs in zip(by_column._state["a"], by_range._state["a"]):
+        np.testing.assert_array_equal(mine, theirs)
+    by_column.assert_matches("a", row, length)
+    by_column.check()
 
 
 def test_kvcache_block_size_must_divide():
