@@ -1,4 +1,4 @@
-"""phi3-mini-3.8b [dense] — RoPE SwiGLU GQA (kv=32) [arXiv:2404.14219]."""
+"""phi3-mini-3.8b [dense] — RoPE SwiGLU MHA (kv=heads=32) [arXiv:2404.14219]."""
 from repro.configs.base import ModelConfig
 
 CONFIG = ModelConfig(

@@ -317,3 +317,48 @@ def per_link_bytes(mode: PsumMode, p: int, nbytes: int,
         rs = (p - 1) / p * nbytes
         return rs * 2 if need_full else rs
     raise ValueError(mode)
+
+
+# --------------------------------------------------------------------------- #
+# Bytes a traced program puts into its collectives.
+# --------------------------------------------------------------------------- #
+#: Primitives of the collectives the modes above emit inside ``shard_map``.
+COLLECTIVE_PRIMITIVES = ("psum", "reduce_scatter", "ppermute", "all_gather")
+
+
+def _sub_jaxprs(params: dict) -> list:
+    out = []
+    for v in params.values():
+        for x in v if isinstance(v, (tuple, list)) else (v,):
+            j = getattr(x, "jaxpr", x)          # ClosedJaxpr -> Jaxpr
+            if hasattr(j, "eqns"):
+                out.append(j)
+    return out
+
+
+def collective_bytes(jaxpr) -> int:
+    """Bytes one device puts into the collectives of ``jaxpr`` (a traced
+    program, e.g. from ``jax.make_jaxpr``) in one run: the operands of
+    every :data:`COLLECTIVE_PRIMITIVES` equation, times the trip count of
+    the scans around it.  Under ``ina`` that is the row-parallel partial
+    sums' bytes; under the ring modes, every hop's.  The operands are as
+    the program holds them, so on the CPU a bfloat16 psum counts at float32
+    (:func:`_needs_f32_workaround`).  Collectives the compiler inserts
+    (``xla_spmd``) are not in the trace and count 0."""
+    total = 0
+    for eqn in getattr(jaxpr, "jaxpr", jaxpr).eqns:
+        name = eqn.primitive.name
+        if name in COLLECTIVE_PRIMITIVES:
+            total += sum(v.aval.size * v.aval.dtype.itemsize
+                         for v in eqn.invars)
+        inner = [collective_bytes(j) for j in _sub_jaxprs(eqn.params)]
+        if name == "cond":                      # one branch runs
+            total += max(inner, default=0)
+        elif name == "while" and any(inner):
+            raise ValueError("a collective inside a while loop runs an "
+                             "unknown number of times")
+        elif name == "scan":
+            total += eqn.params["length"] * sum(inner)
+        else:
+            total += sum(inner)
+    return total

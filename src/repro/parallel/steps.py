@@ -31,6 +31,16 @@ def _data_axes(mesh: Mesh):
     return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 
 
+def cache_placement(model: Model, mesh: Mesh, batch: int, seq: int):
+    """(shapes, shardings) of ``model``'s cache for ``batch`` rows of
+    ``seq`` positions, placed as every step here places it."""
+    cshapes = jax.eval_shape(partial(model.init_cache, batch, seq))
+    cspecs = fit_specs(cache_specs(model.cfg, _data_axes(mesh)), cshapes,
+                       mesh)
+    return cshapes, jax.tree.map(lambda s: NamedSharding(mesh, s), cspecs,
+                                 is_leaf=lambda x: isinstance(x, P))
+
+
 def _with_plan(pctx: Optional[ParallelCtx], mesh: Mesh,
                plan) -> ParallelCtx:
     """The step's ParallelCtx, carrying ``plan`` when one was supplied.
@@ -128,11 +138,8 @@ def build_serve_step(model: Model, mesh: Mesh, shape: ShapeConfig,
     psh = jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs,
                        is_leaf=lambda x: isinstance(x, P))
 
-    cshapes = jax.eval_shape(
-        partial(model.init_cache, shape.global_batch, shape.seq_len))
-    cspecs = fit_specs(cache_specs(cfg, baxes), cshapes, mesh)
-    csh = jax.tree.map(lambda s: NamedSharding(mesh, s), cspecs,
-                       is_leaf=lambda x: isinstance(x, P))
+    cshapes, csh = cache_placement(model, mesh, shape.global_batch,
+                                   shape.seq_len)
 
     bspecs = model.batch_specs(shape, data_axes=baxes)
     _ishapes = model.input_specs(shape)
@@ -196,18 +203,14 @@ def build_paged_serve_step(model: Model, mesh: Mesh, shape: ShapeConfig,
 
     cfg = model.cfg
     pctx = _with_plan(pctx, mesh, plan)
-    baxes = _data_axes(mesh)
     baxis = cache_batch_axes(cfg)
 
     pshapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     pspecs = fit_specs(param_specs(pshapes, mesh), pshapes, mesh)
     psh = jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs,
                        is_leaf=lambda x: isinstance(x, P))
-    cshapes = jax.eval_shape(
-        partial(model.init_cache, shape.global_batch, shape.seq_len))
-    cspecs = fit_specs(cache_specs(cfg, baxes), cshapes, mesh)
-    csh = jax.tree.map(lambda s: NamedSharding(mesh, s), cspecs,
-                       is_leaf=lambda x: isinstance(x, P))
+    cshapes, csh = cache_placement(model, mesh, shape.global_batch,
+                                   shape.seq_len)
 
     has_media = cfg.family in ("encdec", "vlm") and cfg.num_media_tokens
 
@@ -279,19 +282,13 @@ def build_prefill_step(model: Model, mesh: Mesh, shape: ShapeConfig,
     if not model.has_prefill:
         raise NotImplementedError(
             f"family {model.cfg.family!r} has no batched prefill")
-    cfg = model.cfg
     pctx = _with_plan(pctx, mesh, plan)
-    baxes = _data_axes(mesh)
 
     pshapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     pspecs = fit_specs(param_specs(pshapes, mesh), pshapes, mesh)
     psh = jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs,
                        is_leaf=lambda x: isinstance(x, P))
-    cshapes = jax.eval_shape(
-        partial(model.init_cache, shape.global_batch, shape.seq_len))
-    cspecs = fit_specs(cache_specs(cfg, baxes), cshapes, mesh)
-    csh = jax.tree.map(lambda s: NamedSharding(mesh, s), cspecs,
-                       is_leaf=lambda x: isinstance(x, P))
+    _, csh = cache_placement(model, mesh, shape.global_batch, shape.seq_len)
 
     def step(params, batch, cache):
         return model.prefill(params, {"tokens": batch["tokens"]}, cache,

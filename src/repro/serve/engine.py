@@ -8,8 +8,11 @@ they share :mod:`~repro.serve.batching` and the block-accounting rules of
 1. admits queued requests into free cache slots (token boundary only),
 2. prefills each admitted prompt — batched chunked prefill
    (:func:`~repro.parallel.steps.build_prefill_step`) when the family
-   implements ``prefill``, a per-token decode loop otherwise — writing the
-   prompt's K/V into the paged pool and emitting the first token,
+   implements ``prefill``, a per-token decode loop otherwise — into a
+   one-row device cache, emitting the first token; one program seats that
+   row in the request's working-cache slot on the device (at TP each chip
+   copies its own heads), and only the prompt's positions, cut to the next
+   of :func:`prompt_lengths`, come to the host for the paged pool,
 3. runs one vmapped per-slot-position decode step
    (:func:`~repro.parallel.steps.build_paged_serve_step`) over the whole
    slot batch, appends one token per active request, and pages out the
@@ -30,12 +33,17 @@ Each of these is a span of the engine's :class:`~repro.exec.trace.Recorder`
   transfer), a ``pool_write`` per active row, and ``retire``;
 * ``admit`` (one per iteration; the ``admitted`` count where it admits)
   holds a ``prefill`` per request: ``prefill.chunks`` (up to the first
-  token on the host), then ``page_out`` and ``pool_write`` of the prefill
-  row, and ``seat``;
+  token on the host), then ``page_out`` and ``pool_write`` of the prompt's
+  positions, and ``seat``;
 * counters ``page_out_bytes`` (the host arrays' ``nbytes``: every slot's
-  column in decode, the whole row in prefill), ``page_out_rows`` (active
-  rows in decode, one per prefill), ``prefill_chunks``, ``decode_steps``,
-  ``active_slot_steps`` and ``admitted``.
+  column in decode, the prompt's bucketed positions in prefill),
+  ``page_out_rows`` (active rows in decode, one per prefill),
+  ``prefill_chunks``, ``decode_steps``, ``active_slot_steps``,
+  ``admitted``, and ``psum_bytes``: what each device puts into the
+  collectives of the decode step or the prefill program
+  (:func:`~repro.core.collectives.collective_bytes`, counted once from the
+  traced program), charged with every decode step and prefill chunk; 0 at
+  TP 1.
 
 The engine calls ``self.sched.admit``, ``self.prefill_step.fn`` and
 ``self.step.fn`` by attribute at call time, so wrappers put there sit
@@ -86,6 +94,33 @@ class EngineReport:
         return {r["rid"]: r["tokens"] for r in self.requests}
 
 
+def prompt_lengths(chunk: int, max_seq: int) -> tuple[int, ...]:
+    """The lengths a prefill row's page-out is cut to: ``chunk`` times the
+    powers of two below ``max_seq``, then ``max_seq``.  One compile each,
+    and a prompt pages out less than twice its length."""
+    out, n = [], chunk
+    while n < max_seq:
+        out.append(n)
+        n *= 2
+    return tuple(out) + (max_seq,)
+
+
+def prompt_row(rows, length: int, leaves):
+    """Positions ``[0, length)`` of a one-row cache, batch axis removed;
+    whole state for leaves without a sequence axis.  ``leaves`` is the
+    pool's leaf layout (:attr:`PagedKVCache.leaves`)."""
+    import jax
+
+    flat, treedef = jax.tree.flatten(rows)
+    out = []
+    for leaf, m in zip(flat, leaves):
+        row = jax.lax.index_in_dim(leaf, 0, m.batch_axis, keepdims=False)
+        if m.paged:
+            row = jax.lax.slice_in_dim(row, 0, length, axis=m.batch_axis)
+        out.append(row)
+    return treedef.unflatten(out)
+
+
 def gather_columns(working, pos, leaves):
     """The cache column each decode step wrote, for every slot.
 
@@ -128,12 +163,13 @@ class ServingEngine:
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
+        from repro.core.collectives import collective_bytes
         from repro.exec.timing import Stopwatch
         from repro.exec.trace import Recorder
         from repro.launch.mesh import make_host_mesh
         from repro.models.api import get_model
         from repro.parallel.steps import (build_paged_serve_step,
-                                          build_prefill_step)
+                                          build_prefill_step, cache_placement)
         from repro.parallel.tp import ParallelCtx
 
         if cfg.family in _NO_ENGINE_FAMILIES:
@@ -180,6 +216,27 @@ class ServingEngine:
             self._loop_step = jax.jit(
                 lambda p, t, pos, c: self.model.decode_step(
                     p, {"tokens": t, "pos": pos}, c, pctx_p))
+        # The one-row cache prefill writes, placed as the steps place it.
+        rshapes, self._row_sharding = cache_placement(self.model, self.mesh,
+                                                      1, self.max_seq)
+
+        def i32(*shape):
+            return jax.ShapeDtypeStruct(shape, np.int32)
+
+        def traced_bytes(fn, *args):
+            return collective_bytes(jax.make_jaxpr(fn)(*args))
+
+        pshapes = self.step.param_shapes
+        self._psum_bytes = {"decode": traced_bytes(
+            self.step.fn, pshapes, {"tokens": i32(slots, 1), "pos": i32(slots)},
+            self.step.cache_shapes)}
+        if self.prefill_step is not None:
+            self._psum_bytes["prefill"] = traced_bytes(
+                self.prefill_step.fn, pshapes,
+                {"tokens": i32(1, prefill_chunk), "pos0": i32()}, rshapes)
+        else:
+            self._psum_bytes["prefill"] = traced_bytes(
+                self._loop_step, pshapes, i32(1, 1), i32(), rshapes)
         self.setup_s["steps"] = watch.seconds
 
         # Params and caches are made on the device, in the steps' layout:
@@ -195,26 +252,31 @@ class ServingEngine:
         self.working = jax.jit(
             lambda: self.model.init_cache(slots, self.max_seq),
             out_shardings=csh)()
-        if self.prefill_step is not None:
-            self._pcache = jax.jit(
-                lambda: self.model.init_cache(1, self.max_seq),
-                out_shardings=self.prefill_step.cache_sharding)()
-            jax.block_until_ready(self._pcache)
-        jax.block_until_ready(self.working)
+        self._pcache = jax.jit(
+            lambda: self.model.init_cache(1, self.max_seq),
+            out_shardings=self._row_sharding)()
+        jax.block_until_ready((self.working, self._pcache))
         self.setup_s["caches"] = watch.seconds
 
-        def seat(working, row, slot):
+        def seat(working, rows, slot):
             return jax.tree.map(
-                lambda leaf, r, a: jax.lax.dynamic_update_index_in_dim(
-                    leaf, r.astype(leaf.dtype), slot, a),
-                working, row, self.baxis)
+                lambda leaf, r, a: jax.lax.dynamic_update_slice_in_dim(
+                    leaf, r, slot, a),
+                working, rows, self.baxis)
 
-        self._seat_fn = jax.jit(seat, out_shardings=csh, donate_argnums=0)
+        rep = NamedSharding(self.mesh, P())
+        self._seat_fn = jax.jit(
+            seat, in_shardings=(csh, self._row_sharding, rep),
+            out_shardings=csh, donate_argnums=0)
+        self._prompt_lengths = prompt_lengths(prefill_chunk, self.max_seq)
+        self._prompt_row = jax.jit(
+            functools.partial(prompt_row, leaves=self.kv.leaves),
+            static_argnums=1)
 
         # fixed at ``slots`` (inactive slots gather position 0): one compile
         self._columns = jax.jit(
             functools.partial(gather_columns, leaves=self.kv.leaves),
-            in_shardings=(csh, NamedSharding(self.mesh, P())))
+            in_shardings=(csh, rep))
         self._cache_def = jax.tree.structure(self.baxis)
         self._jnp = jax.numpy
         self._jax = jax
@@ -227,11 +289,14 @@ class ServingEngine:
             lambda leaf, a: np.asarray(jnp.take(leaf, slot, axis=a)),
             cache, self.baxis)
 
-    def _page_out(self, cache, slot: int, rid):
-        """``_extract_row`` as a ``page_out`` span, counted."""
+    def _page_out(self, rows, plen: int, rid):
+        """The one-row cache ``rows``' first ``plen`` positions, cut to the
+        next of :attr:`_prompt_lengths`, on the host, as a ``page_out``
+        span, counted."""
+        n = next(n for n in self._prompt_lengths if n >= plen)
         rec = self.recorder
         with rec.span("page_out", rid=rid):
-            row = self._extract_row(cache, slot)
+            row = self._jax.device_get(self._prompt_row(rows, n))
             rec.count("page_out_rows")
             rec.count("page_out_bytes",
                       sum(leaf.nbytes for leaf in self._jax.tree.leaves(row)))
@@ -253,13 +318,15 @@ class ServingEngine:
             offset[m.dtype.name] = o + n
         return out
 
-    def _seat(self, st: RequestState) -> None:
-        """Materialize the request's pooled row into its working-cache
-        slot (zeros past its length — masked by decode attention)."""
+    def _seat(self, st: RequestState, rows) -> None:
+        """Copy the one-row device cache ``rows`` the prompt was prefilled
+        into to the request's working-cache slot, in one program on the
+        device (at TP each chip copies its own heads).  Positions past the
+        prompt hold what an earlier, longer prompt left there: decode
+        writes a position before it attends to it, so none is read."""
         with self.recorder.span("seat", rid=st.req.rid):
-            row = self.kv.gather_row(st.req.rid, st.req.prompt_len)
-            self.working = self._seat_fn(self.working, row,
-                                         self._jnp.asarray(st.slot, np.int32))
+            self.working = self._seat_fn(self.working, rows,
+                                         np.int32(st.slot))
 
     def prefill_logits(self, prompt):
         """Prefill ``prompt`` (token ids) chunk by chunk into the prefill
@@ -295,9 +362,7 @@ class ServingEngine:
                 donate_cache=False, logits=True)
         plen = len(prompt)
         last = self.prefill_logits(prompt)[(plen - 1) % self.prefill_chunk]
-        self.working = self._seat_fn(self.working,
-                                     self._extract_row(self._pcache, 0),
-                                     jnp.asarray(0, np.int32))
+        self.working = self._seat_fn(self.working, self._pcache, np.int32(0))
         toks = np.zeros((self.slots, 1), np.int32)
         toks[0, 0] = int(jnp.argmax(last))
         pos = np.zeros((self.slots,), np.int32)
@@ -332,15 +397,17 @@ class ServingEngine:
             self.working, jnp.zeros((self.slots,), jnp.int32)))
         out["columns"] = watch.seconds
         watch = Stopwatch()
-        row = self._extract_row(self.working, 0)
+        for n in self._prompt_lengths:
+            jax.block_until_ready(self._prompt_row(self._pcache, n))
         self.working = jax.block_until_ready(
-            self._seat_fn(self.working, row, jnp.asarray(0, np.int32)))
+            self._seat_fn(self.working, self._pcache, np.int32(0)))
         out["page_out+seat"] = watch.seconds
         return out
 
     def _prefill(self, st: RequestState) -> None:
-        """Run the prompt, write its K/V into the pool, seat it, and give
-        the request its first generated token."""
+        """Run the prompt into a one-row device cache, write its positions
+        into the pool, seat the row on the device, and give the request
+        its first generated token."""
         jnp = self._jnp
         rec = self.recorder
         req = st.req
@@ -350,7 +417,7 @@ class ServingEngine:
             if self.prefill_step is not None:
                 chunk = self.prefill_chunk
                 logits = self.prefill_logits(prompt)
-                rec.count("prefill_chunks", -(-plen // chunk))
+                runs = -(-plen // chunk)
                 first = int(jnp.argmax(logits[(plen - 1) % chunk]))
                 cache = self._pcache
             else:
@@ -359,11 +426,14 @@ class ServingEngine:
                     lg, cache = self._loop_step(
                         self.params, jnp.asarray(prompt[None, pos:pos + 1]),
                         jnp.asarray(pos, jnp.int32), cache)
-                rec.count("prefill_chunks", plen)
+                runs = plen
                 first = int(jnp.argmax(lg[0, -1]))
-        row = self._page_out(cache, 0, req.rid)
+                cache = self._jax.device_put(cache, self._row_sharding)
+            rec.count("prefill_chunks", runs)
+            rec.count("psum_bytes", runs * self._psum_bytes["prefill"])
+        row = self._page_out(cache, plen, req.rid)
         self._pool_write(req.rid, 0, row, plen)
-        self._seat(st)
+        self._seat(st, cache)
         st.generated.append(first)
 
     # ------------------------------------------------------------------ #
@@ -422,6 +492,7 @@ class ServingEngine:
                     nxt = np.asarray(nxt)
                     rec.count("decode_steps")
                     rec.count("active_slot_steps", len(active))
+                    rec.count("psum_bytes", self._psum_bytes["decode"])
                 with rec.span("page_out"):
                     packed = self._jax.device_get(packed)
                     rec.count("page_out_rows", len(active))

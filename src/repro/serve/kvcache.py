@@ -10,12 +10,13 @@ The serving engine keeps two views of decode state:
   position blocks owned by a free-list :class:`BlockAllocator`; leaves
   without one (SSM states, conv tails) are stored whole per request.
 
-A request's cache row round-trips bit-identically: columns extracted from
-the working cache go into blocks verbatim, and :meth:`PagedKVCache.
-gather_row` reassembles exactly the row the monolithic cache held (zeros
-past the request's length, which decode attention masks out).  That makes
-"paged == monolithic" a checkable invariant rather than a hope — see
-``ServingEngine(check=True)`` and tests/test_serve.py.
+A request's cache row round-trips bit-identically: positions copied from
+the device go into blocks verbatim, and :meth:`PagedKVCache.gather_row`
+reassembles exactly what the monolithic cache holds below the request's
+length (zeros past it, where the working slot may hold stale entries that
+decode attention masks out).  That makes "paged == monolithic" a
+checkable invariant rather than a hope — see ``ServingEngine(check=True)``
+and tests/test_serve.py.
 
 Block accounting is the admission-control currency shared with the
 request-level cluster simulator (:mod:`repro.serve.cluster`): an instance

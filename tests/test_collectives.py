@@ -105,3 +105,62 @@ def test_collectives_on_8_devices():
                           capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, f"stdout:\n{proc.stdout}\nstderr:\n{proc.stderr}"
     assert "COLLECTIVES_OK" in proc.stdout
+
+
+# --------------------------------------------------------------------------- #
+# collective_bytes: traced on an abstract mesh, no devices needed
+# --------------------------------------------------------------------------- #
+#: A row-parallel site traced three times over by a scan: partial [2, 5, 8]
+#: float32 (320 B) on a 4-way model axis.
+SITE_BYTES, SPAN, TRIPS = 2 * 5 * 8 * 4, 4, 3
+
+
+def _scanned_site_jaxpr(mode):
+    import jax
+    import jax.numpy as jnp
+
+    from repro.parallel.tp import ParallelCtx, row_linear
+
+    mesh = jax.sharding.AbstractMesh((SPAN,), ("model",))
+    pctx = ParallelCtx(mesh=mesh, psum_mode=mode)
+
+    def body(x, w):
+        return row_linear(x, w, pctx), None
+
+    def f(x, ws):
+        return jax.lax.scan(body, x, ws)[0]
+
+    return jax.make_jaxpr(f)(jax.ShapeDtypeStruct((2, 5, 8), jnp.float32),
+                             jax.ShapeDtypeStruct((TRIPS, 8, 8), jnp.float32))
+
+
+@pytest.mark.parametrize("mode,per_site", [
+    ("ina", SITE_BYTES),                                  # one psum
+    ("eject_inject", (SPAN - 1) * SITE_BYTES),            # full-tensor hops
+    ("ina_ring", 2 * (SPAN - 1) * SITE_BYTES // SPAN),    # RS + AG chunks
+    ("xla_spmd", 0),                                      # compiler's own
+])
+def test_collective_bytes_counts_every_site_run(mode, per_site):
+    from repro.core.collectives import collective_bytes
+    assert collective_bytes(_scanned_site_jaxpr(mode)) == TRIPS * per_site
+
+
+def test_collective_bytes_takes_one_branch_and_refuses_a_while():
+    import jax
+    import jax.numpy as jnp
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from repro.core.collectives import collective_bytes
+
+    mesh = jax.sharding.AbstractMesh((SPAN,), ("model",))
+    psum = shard_map(lambda a: jax.lax.psum(a, "model"), mesh=mesh,
+                     in_specs=P(), out_specs=P(), check_vma=False)
+    x = jax.ShapeDtypeStruct((10,), jnp.float32)
+    branchy = jax.make_jaxpr(lambda p, a: jax.lax.cond(
+        p, psum, lambda b: psum(psum(b)), a))(True, x)
+    assert collective_bytes(branchy) == 2 * 40
+    looped = jax.make_jaxpr(lambda a: jax.lax.while_loop(
+        lambda c: c[0] < 3, lambda c: (c[0] + 1, psum(c[1])), (0, a)))(x)
+    with pytest.raises(ValueError, match="while loop"):
+        collective_bytes(looped)

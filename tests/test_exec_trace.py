@@ -8,6 +8,7 @@ import pytest
 from repro.configs import ARCHS
 from repro.exec import trace
 from repro.serve import Request, ServingEngine
+from repro.serve.engine import prompt_lengths
 
 
 class FakeClock:
@@ -147,19 +148,51 @@ def test_every_decode_iteration_pages_out_each_active_row(served):
     assert active == c["active_slot_steps"]
 
 
+#: Each prompt length of ``_requests`` and the length its prefill row is
+#: cut to on the way to the host (``prompt_lengths(4, 24)``).
+CUT = {5: 8, 9: 16, 3: 4, 7: 8, 6: 8}
+
+
 def test_page_out_bytes_are_rows_times_the_row(served):
     eng, report, _, row_bytes = served
     c = eng.recorder.counters
     rows = c["active_slot_steps"] + c["admitted"]    # decode + prefill rows
     assert c["page_out_rows"] == rows
-    # decode: every slot's column each step; prefill: the whole row
+    # decode: every slot's column each step; prefill: the prompt's
+    # positions, cut to the next of the prompt lengths
+    assert eng._prompt_lengths == prompt_lengths(4, MAX_SEQ) == (4, 8, 16, 24)
     assert c["page_out_bytes"] == \
         c["decode_steps"] * eng.slots * _column_bytes(eng) + \
-        c["admitted"] * row_bytes
+        sum(CUT[r.prompt_len] for r in _requests()) * _column_bytes(eng)
     assert _column_bytes(eng) * eng.max_seq == row_bytes
     assert c["admitted"] == len(_requests())
     assert c["prefill_chunks"] == report.prefill_chunks == \
         sum(-(-r.prompt_len // 4) for r in _requests())
+
+
+def test_an_admission_pages_out_its_prompt_cut_to_a_length(served):
+    """One prefill's ``page_out``: the bucketed prompt length times one
+    column's bytes, whatever the rest of the row holds."""
+    eng, _, spans, _ = served
+    plen = {r.rid: r.prompt_len for r in _requests()}
+    by_id = {s.id: s for s in spans}
+    outs = [s for s in spans if s.name == "page_out"
+            and by_id[s.parent].name == "prefill"]
+    assert sorted(s.rid for s in outs) == sorted(plen)
+    for s in outs:
+        assert s.counts == {"page_out_rows": 1, "page_out_bytes":
+                            CUT[plen[s.rid]] * _column_bytes(eng)}
+
+
+def test_psum_bytes_count_nothing_at_tp1(served):
+    """On one device the step programs have no collective: the counter is
+    charged with every decode step and prefill chunk, at 0."""
+    eng, _, spans, _ = served
+    assert eng._psum_bytes == {"decode": 0, "prefill": 0}
+    assert eng.recorder.counters["psum_bytes"] == 0
+    for name in ("decode.step", "prefill.chunks"):
+        charged = [s for s in spans if s.name == name]
+        assert charged and all(s.counts["psum_bytes"] == 0 for s in charged)
 
 
 def test_prefill_spans_nest_under_admit(served):

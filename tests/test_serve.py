@@ -25,6 +25,7 @@ from repro.configs import ARCHS
 from repro.serve import (BlockAllocator, PagedKVCache, Request, Scheduler,
                          ServingEngine)
 from repro.serve.cluster import SimKV
+from repro.serve.engine import prompt_lengths
 
 ARCH = ARCHS["qwen2-1.5b"].reduced()
 PROMPT_LEN, GEN, BATCH = 6, 5, 3
@@ -37,11 +38,11 @@ def _prompts():
         jax.random.PRNGKey(7), (BATCH, PROMPT_LEN), 3, ARCH.vocab))
 
 
-@pytest.fixture(scope="module")
-def reference_tokens():
+def legacy_tokens(cfg, model_parallel: int = 1):
     """The legacy launch/serve.py loop: one fixed batch, per-token
-    prefill through the monolithic serve step.  Returns [B, GEN+1] —
-    the first generated token plus GEN greedy continuations."""
+    prefill through the monolithic serve step, on ``model_parallel``
+    devices.  Returns the prompts and [B, GEN+1] — the first generated
+    token plus GEN greedy continuations."""
     import jax
     import jax.numpy as jnp
 
@@ -51,8 +52,8 @@ def reference_tokens():
     from repro.parallel.steps import build_serve_step
     from repro.parallel.tp import ParallelCtx
 
-    model = get_model(ARCH)
-    mesh = make_host_mesh(1)
+    model = get_model(cfg)
+    mesh = make_host_mesh(model_parallel)
     shape = ShapeConfig("test", MAX_SEQ, BATCH, "decode")
     pctx = ParallelCtx(mesh=mesh, psum_mode="ina")
     ss = build_serve_step(model, mesh, shape, pctx, donate_cache=True)
@@ -74,6 +75,11 @@ def reference_tokens():
         out.append(np.asarray(nxt))
         tok = nxt[:, None]
     return prompts, np.stack(out, axis=1)
+
+
+@pytest.fixture(scope="module")
+def reference_tokens():
+    return legacy_tokens(ARCH)
 
 
 def _requests(prompts):
@@ -167,6 +173,121 @@ def test_warmup_compiles_the_column_gather():
     assert compiled == 1
     eng.run(_mixed_requests(ARCH, [(5, 3), (7, 4), (2, 2)]))
     assert eng._columns._cache_size() == compiled
+
+
+def test_warmup_compiles_every_prompt_length():
+    """The prefill row's page-out is cut to one of a few lengths, each
+    compiled by warm-up; the seat is one program."""
+    eng = ServingEngine(ARCH, slots=2, max_seq=24, block_size=4,
+                        prefill_chunk=4)
+    assert eng._prompt_lengths == prompt_lengths(4, 24) == (4, 8, 16, 24)
+    assert prompt_lengths(8, 1024) == (8, 16, 32, 64, 128, 256, 512, 1024)
+    eng.warmup()
+    compiled = (eng._prompt_row._cache_size(), eng._seat_fn._cache_size())
+    assert compiled == (4, 1)
+    eng.run(_mixed_requests(ARCH, [(5, 3), (17, 4), (2, 2), (23, 1)]))
+    assert (eng._prompt_row._cache_size(),
+            eng._seat_fn._cache_size()) == compiled
+
+
+def test_stale_positions_past_a_prompt_are_never_read():
+    """A slot seated from the prefill cache after a longer prompt holds
+    that prompt's entries past its own; the short request's tokens are
+    those of a fresh engine, whose caches hold zeros there."""
+    long_, short = _mixed_requests(ARCH, [(19, 2), (3, 6)])
+
+    def served(reqs):
+        eng = ServingEngine(ARCH, slots=1, max_seq=24, block_size=4,
+                            prefill_chunk=4, check=True)
+        return eng.run(reqs).tokens()
+
+    assert served([long_, short])[short.rid] == served([short])[short.rid]
+
+
+# --------------------------------------------------------------------------- #
+# The engine at TP 4, on four virtual devices (a child process, so the
+# device count is set before JAX starts)
+# --------------------------------------------------------------------------- #
+TP4_SCRIPT = """
+import dataclasses, json, math, sys
+sys.path.insert(0, {tests!r})
+import test_serve as t
+from repro.configs import ARCHS
+from repro.serve import ServingEngine
+
+# phi3-mini's registry entry shrunk, MHA as phi3 is: 4 heads, one a device
+cfg = dataclasses.replace(ARCHS["phi3-mini-3.8b"].reduced(), n_kv_heads=4)
+prompts, ref = t.legacy_tokens(cfg, model_parallel=4)
+eng = ServingEngine(cfg, slots=2, max_seq=t.MAX_SEQ, block_size=4,
+                    prefill_chunk=4, check=True, model_parallel=4)
+rep = eng.run(t._requests(prompts))
+spans = eng.recorder.spans()
+by_id = {{s.id: s for s in spans}}
+column = sum(math.prod(m.row_shape) // eng.max_seq * m.dtype.itemsize
+             for m in eng.kv.leaves if m.paged)
+print("RESULT", json.dumps({{
+    "tokens": rep.tokens(), "ref": ref.tolist(), "checks": rep.checks,
+    "prefill_page_out": [s.counts["page_out_bytes"] for s in spans
+                         if s.name == "page_out"
+                         and by_id[s.parent].name == "prefill"],
+    "column_bytes": column, "psum_bytes": eng._psum_bytes,
+    "charged": eng.recorder.counters["psum_bytes"],
+    "decode_steps": rep.decode_steps, "prefill_chunks": rep.prefill_chunks,
+    "sizes": [cfg.n_layers, cfg.d_model, eng.slots, eng.prefill_chunk],
+    "shards": [len(leaf.sharding.device_set)
+               for leaf in eng.working.values()]}}))
+"""
+
+
+@pytest.fixture(scope="module")
+def tp4(tmp_path_factory):
+    import json
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    tmp = tmp_path_factory.mktemp("tp4")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               REPRO_PLAN_DIR=str(tmp / "plans"),
+               REPRO_SIMCACHE_DIR=str(tmp / "simcache"))
+    code = TP4_SCRIPT.format(tests=str(Path(__file__).resolve().parent))
+    p = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, env=env, timeout=600)
+    line = [ln for ln in p.stdout.splitlines() if ln.startswith("RESULT")]
+    assert line, p.stderr[-3000:]
+    return json.loads(line[0][len("RESULT"):])
+
+
+def test_tp4_engine_matches_legacy_loop(tp4):
+    assert tp4["shards"] == [4, 4]               # K and V split by heads
+    for i in range(BATCH):
+        assert tp4["tokens"][f"r{i}"] == tp4["ref"][i], f"r{i} diverged"
+
+
+def test_tp4_pool_matches_working_cache(tp4):
+    """check=True: the row seated on the device from the prefill cache,
+    and the columns decode wrote, equal the pool at every retirement."""
+    assert tp4["checks"] == BATCH
+
+
+def test_tp4_admission_pages_out_the_bucketed_prompt(tp4):
+    cut = next(n for n in prompt_lengths(4, MAX_SEQ) if n >= PROMPT_LEN)
+    assert cut == 8
+    assert tp4["prefill_page_out"] == [cut * tp4["column_bytes"]] * BATCH
+
+
+def test_tp4_psum_bytes_are_the_row_parallel_partials(tp4):
+    """Two row-parallel psums a layer (attention out, MLP down), each of
+    a ``[tokens, d_model]`` float32 partial: the decode step's tokens are
+    its slots, a prefill chunk's its chunk."""
+    layers, d, slots, chunk = tp4["sizes"]
+    assert tp4["psum_bytes"] == {"decode": 2 * layers * slots * d * 4,
+                                 "prefill": 2 * layers * chunk * d * 4}
+    assert tp4["charged"] == \
+        tp4["decode_steps"] * tp4["psum_bytes"]["decode"] + \
+        tp4["prefill_chunks"] * tp4["psum_bytes"]["prefill"]
 
 
 def test_engine_rejects_promptless_and_oversized():
