@@ -42,6 +42,14 @@ class Model:
                 lambda p: p.astype(pd) if p.ndim >= 2 else p, params)
         return params
 
+    def init_served(self, key):
+        """:meth:`init`'s params as the serving engine holds them: each
+        leaf that :func:`served_dtypes` puts at ``cfg.dtype`` cast there,
+        in the same program, the rest as :meth:`init` makes them."""
+        params = self.init(key)
+        return jax.tree.map(lambda p, dt: p.astype(dt), params,
+                            served_dtypes(self.cfg, params))
+
     def forward(self, params, batch, pctx=None):
         return self.mod.forward(params, self.cfg, batch, pctx)
 
@@ -183,6 +191,45 @@ def param_specs(params, mesh=None) -> dict:
     mesh_shape = dict(mesh.shape) if mesh is not None else None
     return jax.tree_util.tree_map_with_path(
         lambda p, l: _leaf_spec(p, l, mesh_shape), params)
+
+
+# --------------------------------------------------------------------------- #
+# served dtypes: which leaves serving holds at the compute dtype
+# --------------------------------------------------------------------------- #
+_NORMS = (("ln1",), ("ln2",), ("ln_f",), ("q_norm",), ("k_norm",))
+# Experts: the CPU expert-parallel path computes them in float32.
+_EXPERTS = (("layers", "mlp", "w_gate"), ("layers", "mlp", "w_up"),
+            ("layers", "mlp", "w_down"))
+#: Per family served by the engine, the leaves (as the tail of their path)
+#: that some use reads in float32: norm scales, the recurrences' decay and
+#: bonus parameters, MoE experts.  Every other leaf of these families is
+#: read only through a cast to ``cfg.dtype``.
+_FLOAT32_USES = {
+    "dense": _NORMS,
+    "moe": _NORMS + _EXPERTS,
+    "mla_moe": _NORMS + (("kv_norm",),) + _EXPERTS,
+    "ssm": _NORMS + (("ln_in",), ("ln_x",), ("w0",), ("u",)),
+    "hybrid": _NORMS + (("ln",), ("inv_norms",), ("gate_norm",),
+                        ("A_log",), ("dt_bias",)),
+}
+
+
+def served_dtypes(cfg: ModelConfig, params) -> dict:
+    """Pytree (mirroring ``params``) of the dtype serving holds each leaf
+    at: ``cfg.dtype`` for a leaf that every use casts to it, so the held
+    copy has the values each step would make; the leaf's own dtype for
+    one read in float32 somewhere, and for every leaf of a family not
+    listed in ``_FLOAT32_USES``."""
+    keep = _FLOAT32_USES.get(cfg.family)
+    dt = jnp.dtype(cfg.dtype)
+
+    def dtype(path, leaf):
+        names = tuple(getattr(p, "key", getattr(p, "name", "")) for p in path)
+        if keep is None or any(names[-len(t):] == t for t in keep):
+            return leaf.dtype
+        return dt
+
+    return jax.tree_util.tree_map_with_path(dtype, params)
 
 
 def cache_batch_axes(cfg: ModelConfig) -> dict:

@@ -55,6 +55,12 @@ positions ``> pos``), so joining or leaving the batch can never change a
 request's tokens — the property tests/test_serve.py pins against the
 legacy one-batch loop.
 
+The params are made on the device at build, each weight that every use
+casts to the compute dtype already cast there
+(:meth:`~repro.models.api.Model.init_served`): one copy, read by every
+step with no per-call convert.  ``held_param_bytes`` records its bytes
+per dtype on the fullest device.
+
 Prefill/decode are disaggregated: each phase carries its own
 ``ParallelCtx`` (and, under ``--psum-mode auto``, its own
 :class:`~repro.plan.ExecutionPlan` via ``plan_for_launch`` — see
@@ -152,6 +158,19 @@ def gather_columns(working, pos, leaves):
     return {d: jnp.concatenate(cols, axis=1) for d, cols in packed.items()}
 
 
+def held_bytes(params) -> dict:
+    """Bytes of ``params`` on the device that holds the most of them, per
+    dtype name."""
+    import jax
+
+    per = {}
+    for leaf in jax.tree.leaves(params):
+        for sh in leaf.addressable_shards:
+            dev = per.setdefault(sh.device, {})
+            dev[leaf.dtype.name] = dev.get(leaf.dtype.name, 0) + sh.data.nbytes
+    return max(per.values(), key=lambda d: sum(d.values()))
+
+
 class ServingEngine:
     def __init__(self, cfg: ModelConfig, *, slots: int = 4,
                  max_seq: Optional[int] = None, block_size: int = 16,
@@ -226,7 +245,9 @@ class ServingEngine:
         def traced_bytes(fn, *args):
             return collective_bytes(jax.make_jaxpr(fn)(*args))
 
-        pshapes = self.step.param_shapes
+        # The params as held (:meth:`~repro.models.api.Model.init_served`)
+        pshapes = jax.eval_shape(self.model.init_served,
+                                 jax.random.PRNGKey(param_seed))
         self._psum_bytes = {"decode": traced_bytes(
             self.step.fn, pshapes, {"tokens": i32(slots, 1), "pos": i32(slots)},
             self.step.cache_shapes)}
@@ -241,12 +262,16 @@ class ServingEngine:
 
         # Params and caches are made on the device, in the steps' layout:
         # no host copy, and no staging of the whole model on one device
-        # when the params are sharded.
+        # when the params are sharded.  The leaves every use casts to the
+        # compute dtype are held there, cast in the same program: the steps
+        # read the values they would make, without a convert per call.
         watch = Stopwatch()
         self.params = jax.block_until_ready(jax.jit(
-            self.model.init, out_shardings=self.step.param_sharding)(
+            self.model.init_served, out_shardings=self.step.param_sharding)(
                 jax.random.PRNGKey(param_seed)))
         self.setup_s["params"] = watch.seconds
+        #: bytes of params the fullest device holds, per dtype name
+        self.held_param_bytes = held_bytes(self.params)
         watch = Stopwatch()
         csh = self.step.cache_sharding
         self.working = jax.jit(

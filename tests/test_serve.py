@@ -205,6 +205,123 @@ def test_stale_positions_past_a_prompt_are_never_read():
 
 
 # --------------------------------------------------------------------------- #
+# Params held at the compute dtype
+# --------------------------------------------------------------------------- #
+# one registry entry per family the engine serves
+SERVED_FAMILIES = {"dense": "qwen2-1.5b", "moe": "llama4-scout-17b-16e",
+                   "mla_moe": "deepseek-v2-lite-16b", "ssm": "rwkv6-7b",
+                   "hybrid": "zamba2-2.7b"}
+
+
+def _bf16_engine(arch, **kw):
+    cfg = dataclasses.replace(ARCHS[arch].reduced(), dtype="bfloat16")
+    return ServingEngine(cfg, slots=2, max_seq=24, block_size=4,
+                         prefill_chunk=4, **kw)
+
+
+def _float32_params(eng):
+    """The engine's params as :meth:`Model.init` makes them: all float32,
+    in the steps' layout."""
+    import jax
+    return jax.jit(eng.model.init, out_shardings=eng.step.param_sharding)(
+        jax.random.PRNGKey(0))
+
+
+@pytest.mark.parametrize("family", sorted(SERVED_FAMILIES))
+def test_held_params_serve_what_float32_params_serve(family):
+    """At a bfloat16 compute dtype, the params the engine holds (the
+    leaves every use casts, cast once at build) give bit for bit the
+    per-slot decode logits, prefill logits and tokens of float32 params
+    that each step casts."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.parallel.steps import build_paged_serve_step
+
+    eng = _bf16_engine(SERVED_FAMILIES[family])
+    cfg, held, f32 = eng.cfg, eng.params, _float32_params(eng)
+    assert {leaf.dtype for leaf in jax.tree.leaves(f32)} == {np.dtype("float32")}
+    assert jnp.dtype("bfloat16") in {leaf.dtype
+                                     for leaf in jax.tree.leaves(held)}
+
+    lens = [(5, 6), (9, 4), (3, 7)]
+    tokens = {}
+    for name, params in (("held", held), ("f32", f32)):
+        eng.params = params
+        tokens[name] = eng.run(_mixed_requests(cfg, lens)).tokens()
+    assert tokens["held"] == tokens["f32"]
+
+    step = build_paged_serve_step(eng.model, eng.mesh, eng._dshape,
+                                  eng._pctx_d, donate_cache=False, logits=True)
+    batch = {"tokens": jnp.asarray([[5], [9]], jnp.int32),
+             "pos": jnp.asarray([7, 12], jnp.int32)}
+    decode = [np.asarray(step.fn(p, batch, eng.working)[0])
+              for p in (held, f32)]
+    np.testing.assert_array_equal(*decode)
+    if eng.prefill_step is not None:
+        prefill = []
+        for p in (held, f32):
+            eng.params = p
+            prefill.append(np.asarray(eng.prefill_logits(np.arange(3, 10))))
+        np.testing.assert_array_equal(*prefill)
+
+
+def _weight_converts(fn, held, params, *args) -> list:
+    """Shapes that a convert in ``fn``'s program, lowered with ``params``,
+    takes from a float32 operand shaped like a bfloat16 matrix of the
+    held params ``held``: whole, one layer of it, or transposed."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    shapes = set()
+    for leaf in jax.tree.leaves(held):
+        if leaf.dtype == jnp.bfloat16 and leaf.ndim >= 2:
+            for s in (leaf.shape, leaf.shape[1:]):
+                if len(s) >= 2:
+                    shapes |= {tuple(s), tuple(s[::-1])}
+    text = fn.lower(params, *args).as_text()
+    found = re.findall(r"stablehlo\.convert[^\n]*\(tensor<([0-9x]+)xf32>\) "
+                       r"-> tensor<[0-9x]+xbf16>", text)
+    return [s for s in found
+            if tuple(int(n) for n in s.split("x")) in shapes]
+
+
+def test_held_programs_cast_no_weight():
+    """The tiny dense config's decode and prefill programs, lowered with
+    the held params, convert no float32 weight to bfloat16; with float32
+    params they convert at least five."""
+    import jax.numpy as jnp
+
+    eng = _bf16_engine("qwen2-1.5b")
+    decode = ({"tokens": jnp.zeros((2, 1), jnp.int32),
+               "pos": jnp.zeros((2,), jnp.int32)}, eng.working)
+    prefill = ({"tokens": jnp.zeros((1, 4), jnp.int32),
+                "pos0": jnp.asarray(0, jnp.int32)}, eng._pcache)
+    f32 = _float32_params(eng)
+    for fn, args in ((eng.step.fn, decode), (eng.prefill_step.fn, prefill)):
+        assert _weight_converts(fn, eng.params, eng.params, *args) == []
+        assert len(_weight_converts(fn, eng.params, f32, *args)) >= 5
+
+
+def test_held_param_bytes_split_by_dtype():
+    """``held_param_bytes`` is the bytes the harness counts per chip,
+    split by dtype: the norm scales in float32, the rest in bfloat16."""
+    import jax
+
+    from bench.run import param_bytes_per_chip
+
+    eng = _bf16_engine("qwen2-1.5b")
+    held, params = eng.held_param_bytes, eng.params
+    assert sum(held.values()) == param_bytes_per_chip(params)
+    norms = [params["ln_f"], params["layers"]["ln1"], params["layers"]["ln2"]]
+    assert held["float32"] == sum(n.nbytes for n in norms)
+    assert held["bfloat16"] == sum(
+        leaf.nbytes for leaf in jax.tree.leaves(params)) - held["float32"]
+
+
+# --------------------------------------------------------------------------- #
 # The engine at TP 4, on four virtual devices (a child process, so the
 # device count is set before JAX starts)
 # --------------------------------------------------------------------------- #

@@ -4,8 +4,9 @@ Interpret mode accepts kernels that the chip's compiler (Mosaic) refuses,
 so each kernel is compiled here for a v5e that is described, not attached:
 ``ina_matmul`` and ``flash_attention`` at qwen2-1.5b widths, ``wkv6`` at
 rwkv6-7b widths.  The serving engine's column gather is compiled the same
-way at qwen2-1.5b's cache, for the bytes it moves.  Only shapes are
-needed; nothing runs.
+way at qwen2-1.5b's cache, for the bytes it moves, and its decode and
+prefill steps with the params as it holds them, for the weights they
+convert.  Only shapes are needed; nothing runs.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process may load the TPU library, and under
@@ -97,3 +98,72 @@ def test_column_gather_reads_only_the_columns(one_chip):
     cost = cost[0] if isinstance(cost, list) else cost
     assert cost["bytes accessed"] < 16 * out
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * out
+
+
+def _whole_weight_ops(text: str, weights) -> list:
+    """Converts, copies and transposes in compiled HLO ``text`` whose
+    result has the shape of a whole weight, in any order of its axes."""
+    import re
+
+    shapes = {tuple(sorted(w.shape)) for w in weights}
+    ops = re.findall(r"= \w+\[([0-9,]+)\]\{[^}]*\} (convert|copy|transpose)\(",
+                     text)
+    return [(shape, op) for shape, op in ops
+            if tuple(sorted(int(n) for n in shape.split(","))) in shapes]
+
+
+def test_served_steps_read_the_held_weights_as_held(one_chip):
+    """qwen2-1.5b's decode step (32 slots, max_seq 1024) and prefill step
+    (8-token chunks), compiled with the params as the serving engine holds
+    them, convert, copy or transpose no whole weight: the per-step casts
+    of the float32 weights are gone, and no relayout (of the tied head's
+    table, say) takes their place.  Compiled with float32 params, the
+    decode step converts every matrix."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.configs import ARCHS
+    from repro.configs.base import ShapeConfig
+    from repro.models.api import get_model
+    from repro.parallel.steps import (build_paged_serve_step,
+                                      build_prefill_step, cache_placement)
+    from repro.parallel.tp import ParallelCtx
+
+    model = get_model(ARCHS["qwen2-1.5b"])
+    mesh = Mesh(np.array([one_chip.device_set.pop()]).reshape(1, 1),
+                ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+    pctx = ParallelCtx(mesh=mesh, psum_mode="ina")
+    step = build_paged_serve_step(model, mesh,
+                                  ShapeConfig("serve", 1024, 32, "decode"),
+                                  pctx)
+    prefill = build_prefill_step(model, mesh,
+                                 ShapeConfig("serve", 1024, 1, "prefill"), 8,
+                                 pctx)
+    rshapes, rsharding = cache_placement(model, mesh, 1, 1024)
+
+    def placed(shapes, shardings):
+        return jax.tree.map(lambda s, sh: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=sh), shapes, shardings)
+
+    key = jax.random.PRNGKey(0)
+    held = placed(jax.eval_shape(model.init_served, key), step.param_sharding)
+    f32 = placed(jax.eval_shape(model.init, key), step.param_sharding)
+    weights = [w for w in jax.tree.leaves(held) if w.dtype == BF16]
+    assert len(weights) == 11             # embed, 7 attention, 3 MLP
+    rep = NamedSharding(mesh, P())
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=rep)
+
+    decode = ({"tokens": i32(32, 1), "pos": i32(32)},
+              placed(step.cache_shapes, step.cache_sharding))
+    chunk = ({"tokens": i32(1, 8), "pos0": i32()}, placed(rshapes, rsharding))
+
+    def ops(fn, params, args):
+        text = fn.lower(params, *args).compile().as_text()
+        return _whole_weight_ops(text, weights)
+
+    assert ops(step.fn, held, decode) == []
+    assert ops(prefill.fn, held, chunk) == []
+    assert {op for _, op in ops(step.fn, f32, decode)} == {"convert"}
+    assert len(ops(step.fn, f32, decode)) >= len(weights)
